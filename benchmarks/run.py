@@ -26,6 +26,7 @@ import time
 from benchmarks import _host_mesh  # noqa: F401  (must precede jax import)
 from benchmarks import churn_bench, fig45_bounds, figures, sweep_bench
 from benchmarks.roofline_bench import print_table, sweep_tick_row, table
+from repro.core.compile_cache import enable_compile_cache
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "results",
                        "benchmarks")
@@ -129,7 +130,7 @@ def main(argv=None) -> None:
                          "PRs argue from these traces")
     a = ap.parse_args(argv)
     os.makedirs(OUT_DIR, exist_ok=True)
-    sweep_bench.enable_compile_cache()
+    enable_compile_cache()
 
     if a.profile:
         import jax

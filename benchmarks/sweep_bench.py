@@ -41,63 +41,18 @@ from benchmarks import _host_mesh  # noqa: F401  (must precede jax import)
 
 import jax  # noqa: E402  (after the device-count bootstrap, by design)
 
-from repro.core import env                              # noqa: E402
+from repro.core.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.barriers import make_barrier            # noqa: E402
 from repro.core.simulator import SimConfig, run_simulation  # noqa: E402
 from repro.core.sweep_plan import parse_mesh, resolve_mesh  # noqa: E402
 from repro.core.vector_sim import run_sweep             # noqa: E402
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_sweep.json")
-CACHE_DIR = os.path.join(os.path.dirname(__file__), "..", ".jax_cache")
 
 FIVE = ("bsp", "ssp", "asp", "pbsp", "pssp")
 ADAPTIVE = ("dssp", "ebsp", "apbsp", "apssp")
 NINE = FIVE + ADAPTIVE
 FRACS = (0.0, 0.05, 0.1, 0.2, 0.3)
-
-
-def enable_compile_cache() -> bool:
-    """Switch on JAX's persistent compilation cache for benchmark runs.
-
-    ROADMAP: the smoke sweep pays ~10× more compile than run time, so
-    repeated benchmark invocations (CI gate, local iteration) should hit
-    the on-disk cache instead of re-lowering identical chunk shapes.
-    The cache lives in repo-root ``.jax_cache`` (override with
-    ``JAX_COMPILATION_CACHE_DIR``); set ``PSP_NO_COMPILE_CACHE=1`` to
-    opt out — e.g. when *measuring* cold-compile cost itself.  Returns
-    whether the cache is active.
-
-    **CPU hosts default to off.**  The image's jaxlib (0.4.37) corrupts
-    the heap when it deserializes the large donated sharded-scan chunk
-    executable from the cache on the CPU backend — observed as wrong
-    sweep results followed by glibc ``corrupted double-linked list`` /
-    ``malloc`` aborts, with or without
-    ``jax_persistent_cache_enable_xla_caches``; small executables
-    round-trip fine, so this is a size/donation-dependent
-    deserialization bug, not a config problem.  Accelerator backends use
-    XLA's well-trodden serialization path and keep the cache on.  Set
-    ``PSP_COMPILE_CACHE=1`` to force it on anyway (e.g. on a host with a
-    newer jaxlib).
-    """
-    if env.flag("PSP_NO_COMPILE_CACHE"):
-        return False
-    if jax.default_backend() == "cpu" and not env.flag("PSP_COMPILE_CACHE"):
-        return False
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                               os.path.abspath(CACHE_DIR))
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # smoke-scale chunks compile in well under the default 1 s
-        # threshold — cache everything, whatever its size
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # don't bundle XLA's own autotune/kernel caches into the entry;
-        # the executable alone is what amortizes recompiles
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
-    except AttributeError:          # ancient jax: no persistent cache
-        return False
-    os.makedirs(cache_dir, exist_ok=True)
-    return True
 
 
 def _configs(full: bool):
@@ -162,6 +117,10 @@ def hundred_k_row() -> Dict:
     mesh_before = os.environ.get("PSP_SWEEP_MESH")
     if mesh_before is None:
         os.environ["PSP_SWEEP_MESH"] = f"1x{len(jax.devices())}"
+    # the Pallas tick cannot hold P = 100 000 in VMEM (it refuses at
+    # trace time), so this row names the reference tick explicitly
+    impl_before = os.environ.get("PSP_TICK_IMPL")
+    os.environ["PSP_TICK_IMPL"] = "ref"
     try:
         t0 = time.time()
         run_sweep(cfgs, backend="jax")
@@ -177,8 +136,13 @@ def hundred_k_row() -> Dict:
     finally:
         if mesh_before is None:
             os.environ.pop("PSP_SWEEP_MESH", None)
+        if impl_before is None:
+            os.environ.pop("PSP_TICK_IMPL", None)
+        else:
+            os.environ["PSP_TICK_IMPL"] = impl_before
         vector_sim_jax._compiled_chunk.cache_clear()
     row.update({
+        "tick_impl": "ref",
         "seconds": best,
         "compile_seconds": max(compile_s - best, 0.0),
         "n_nodes": cfgs[0].n_nodes,
@@ -253,7 +217,7 @@ def sweep_speedup(full: bool = False, backend: str | None = None,
     ``mesh`` / ``mesh_axes``; results are bit-identical across
     factorizations, so the mesh only moves the timings.
     """
-    cache_on = enable_compile_cache()
+    cache_on = enable_compile_cache() is not None
     mesh_before = os.environ.get("PSP_SWEEP_MESH")
     if mesh is not None:
         parse_mesh(mesh)                       # reject typos loudly, now
@@ -282,8 +246,8 @@ def _sweep_speedup(full: bool, pallas: bool, out_path: str | None,
     if pallas:
         # Pallas tick kernel: the interpreter lowers it to XLA on CPU, so
         # this times kernel *semantics* end-to-end; on a TPU host the same
-        # row times the real fused Mosaic kernel (impl="auto")
-        impl = "auto" if jax.default_backend() == "tpu" else "interpret"
+        # row times the Mosaic-compiled kernel (named, never "auto")
+        impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
         compile_t["pallas"], timings["pallas"], per_engine["pallas"] = \
             _timed_grid(cfgs, "jax", impl=impl)
     t0 = time.time()

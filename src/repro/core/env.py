@@ -62,12 +62,9 @@ REGISTRY: Dict[str, EnvVar] = _reg(
     EnvVar("PSP_TICK_IMPL", "str", "auto",
            "PSP tick kernel dispatch: `auto` | `pallas` | `interpret` "
            "| `ref`"),
-    EnvVar("PSP_COMPILE_CACHE", "flag", False,
-           "force the persistent JAX compile cache ON even on CPU "
-           "(default off there: jaxlib 0.4.37 heap corruption)"),
     EnvVar("PSP_NO_COMPILE_CACHE", "flag", False,
-           "opt out of the persistent JAX compile cache everywhere "
-           "(e.g. when measuring cold-compile cost)"),
+           "turn off the persistent JAX compile cache (default on, at "
+           "`JAX_COMPILATION_CACHE_DIR` or `<checkout>/.jax_cache`)"),
     EnvVar("PSP_BENCH_HOST_DEVICES", "int", None,
            "forced host-device count for CPU benchmark runs "
            "(`0` disables the forced mesh; default: one per core, "

@@ -678,9 +678,13 @@ def linear_psp_task(dim: int, lr: float = 0.1, seed: int = 0):
         / np.sqrt(dim)
 
     def grad_fn(params, batch):
+        # the mean as a sequential (cumulative) sum: XLA's CPU reduction
+        # vectorizes a batched (vmapped) row differently from a solo one,
+        # which would move the loss by an ulp between the two
         x, y = batch
         return jax.value_and_grad(
-            lambda p: jnp.mean((x @ p["w"] - y) ** 2))(params)
+            lambda p: jnp.cumsum((x @ p["w"] - y) ** 2)[-1] / y.shape[-1]
+        )(params)
 
     def opt_update(g, s, p):
         return jax.tree.map(lambda gi: -lr * gi, g), s

@@ -99,7 +99,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import env
@@ -279,16 +278,17 @@ def _compiled_chunk(P: int, d: int, batch: int, k_max: int, has_churn: bool,
 
     def sharded(params, carry, xs):
         specs = _specs(params, carry, xs)
-        # check_rep=False: pallas_call (the interpret/TPU tick) has no
-        # replication rule; correctness is pinned by the cross-mesh
+        # check_vma=False: pallas_call (the interpret/TPU tick) has no
+        # varying-axes rule; correctness is pinned by the cross-mesh
         # bit-identity suite instead.  Traces (and the per-row carry) are
         # replicated over the nodes axis — their out_specs mention only
         # rows, so shard_map keeps one copy
-        return shard_map(chunk, mesh=mesh, in_specs=specs,
-                         out_specs=(specs[1],
-                                    (PartitionSpec(None, SWEEP_ROWS_AXIS),
-                                     PartitionSpec(None, SWEEP_ROWS_AXIS))),
-                         check_rep=False)(params, carry, xs)
+        return jax.shard_map(chunk, mesh=mesh, in_specs=specs,
+                             out_specs=(specs[1],
+                                        (PartitionSpec(None, SWEEP_ROWS_AXIS),
+                                         PartitionSpec(None,
+                                                       SWEEP_ROWS_AXIS))),
+                             check_vma=False)(params, carry, xs)
 
     return jax.jit(sharded, donate_argnums=(1,)), mesh
 

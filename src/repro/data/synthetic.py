@@ -21,6 +21,12 @@ import numpy as np
 from repro.parallel.sharding import AxisRules
 
 
+#: largest vocabulary given a dense ``(v, v)`` transition table (64 MB);
+#: a published vocabulary (151,936 for qwen2) would need 92 GB of host
+#: memory, so larger ones use a table-free order-1 process instead
+DENSE_VOCAB_MAX = 4096
+
+
 @dataclasses.dataclass
 class SyntheticLM:
     vocab_size: int
@@ -33,10 +39,12 @@ class SyntheticLM:
     def __post_init__(self):
         rng = np.random.default_rng(self.seed)   # shared task definition
         v = self.vocab_size
-        # order-1 transition logits with strong structure + noise
-        self._trans = rng.normal(size=(v, v)).astype(np.float32)
-        self._trans += 3.0 * np.eye(v, k=1, dtype=np.float32)[
-            np.arange(v)[:, None] % v, np.arange(v)[None, :] % v]
+        self._trans = None
+        if v <= DENSE_VOCAB_MAX:
+            # order-1 transition logits with strong structure + noise
+            self._trans = rng.normal(size=(v, v)).astype(np.float32)
+            self._trans += 3.0 * np.eye(v, k=1, dtype=np.float32)[
+                np.arange(v)[:, None] % v, np.arange(v)[None, :] % v]
         self._rng = np.random.default_rng(
             np.random.SeedSequence([self.seed, self.shard]))
 
@@ -44,6 +52,14 @@ class SyntheticLM:
         v = self.vocab_size
         seq = np.empty(self.seq_len, dtype=np.int32)
         seq[0] = self._rng.integers(v)
+        if self._trans is None:
+            # table-free: the successor token with probability ½, else a
+            # uniform draw
+            follow = self._rng.random(self.seq_len) < 0.5
+            draw = self._rng.integers(v, size=self.seq_len)
+            for i in range(1, self.seq_len):
+                seq[i] = (seq[i - 1] + 1) % v if follow[i] else draw[i]
+            return seq
         # vectorised Gumbel-max over the transition row
         for i in range(1, self.seq_len):
             logits = self._trans[seq[i - 1]]

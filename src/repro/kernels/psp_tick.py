@@ -1,20 +1,19 @@
-"""Pallas TPU kernel for one full PSP sweep-grid tick (control + data plane).
+"""Pallas TPU kernel for one PSP sweep-grid tick's control plane.
 
 One grid tick of the vectorized sweep engine
-(:mod:`repro.core.vector_sim_jax`) is two very different workloads glued
-together: a *control-plane* update over the ``(B, P)`` scenario state —
-churn, finish bookkeeping, the masked-min full-view barrier, the β-sample
+(:mod:`repro.core.vector_sim_jax`) is two very different workloads: a
+*control-plane* update over the ``(B, P)`` scenario state — churn,
+finish bookkeeping, the masked-min full-view barrier, the β-sample
 barrier predicate, and start/re-poll anchoring — and a *data-plane* SGD
-push (minibatch residual + gradient + server update + model-view pull of
-the linear task).  The control plane is a swarm of tiny masked
-element-wise ops and row reductions; the data plane is two small
-contractions per scenario row.  Left to XLA the pair becomes dozens of
-kernels per tick; this module fuses the **whole tick** into one Pallas
-kernel, one grid step per :data:`DATA_PLANE_BLOCK`-row scenario block,
-so a tick runs out of VMEM with no intermediate HBM traffic — the
-barrier logic feeds the gradient mask directly, and the updated server
-model is pulled into the block's node views without ever leaving the
-kernel.
+push (minibatch residual + gradient + server update + model-view pull
+of the linear task).  The control plane is a swarm of tiny masked
+element-wise ops and row reductions, which this module fuses into one
+Pallas kernel, one grid step per :data:`KERNEL_ROWS`-row scenario block.
+The data plane is two contractions per row block over the ``(P, m, d)``
+minibatch blob and the ``(B, P, d)`` node views (32 MB and 64 MB at
+paper scale, more than VMEM holds); it runs as XLA code
+(:func:`_data_plane`) on the kernel's finisher/starter masks, shared
+verbatim by both implementations.
 
 Two implementations, held tick-for-tick identical by
 ``tests/test_kernels.py``:
@@ -64,10 +63,11 @@ The data-plane noise is shared across rows (``X`` f32[P, m, d] minibatch
 features, ``mb`` f32[P, m] label noise) — each row's marginal is an exact
 fresh draw; cross-row correlation is irrelevant for per-row statistics.
 
-VMEM budget: the dominant buffers are one ``P × P`` f32 score matrix per
-grid row (~4 MB at P = 1024) and the shared ``P × m × d`` minibatch blob;
-both comfortably resident for the paper-scale shapes, P beyond ~1500
-would need a lane-tiled variant.
+VMEM budget: the dominant buffer is the ``P × P`` f32 score tile (~4 MB
+at P = 1000; one per row when churn masks the sample); the rank test
+streams it in :data:`PEER_CHUNK`-peer slices.  :func:`_vmem_estimate`
+bounds the kernel's need, and a fleet over :data:`VMEM_BUDGET` (the
+100k-node sweep) is refused at trace time.
 """
 from __future__ import annotations
 
@@ -79,6 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import barrier_kernel
 
@@ -130,10 +131,49 @@ def _data_plane_block(X: jax.Array, diff: jax.Array, fin: jax.Array,
     resid = (jnp.sum(X[None] * diff[:, :, None, :], axis=-1)
              - noise_std[:, None, None] * mb[None])
     resid = jnp.where(fin[:, :, None], resid, 0.0)
-    gsum = jnp.einsum("kpm,pmd->kd", resid, X) / m
+    gsum = jnp.einsum("kpm,pmd->kd", resid, X,
+                      precision=lax.Precision.HIGHEST) / m
     w_new = w - lr[:, None] * gsum
     pulled_new = jnp.where(start[..., None], w_new[:, None, :], pulled)
     return w_new, pulled_new
+
+def _data_plane(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
+                params: Dict[str, jax.Array], fin: jax.Array,
+                start: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The whole batch's data plane, in fixed-width row blocks.
+
+    The fin mask zeroes non-finisher residuals, so frozen/inactive rows
+    see w − lr·0 — exactly w.  Executed in :data:`DATA_PLANE_BLOCK`-row
+    blocks: the GEMM shapes never follow the batch/shard width, so each
+    row's bits are independent of how rows are grouped — the
+    sharded-sweep bit-identity invariant.  Both tick implementations run
+    this same XLA code after their control plane, so they agree bit for
+    bit whenever their ``fin``/``start`` masks do.
+
+    Returns:
+      (w', pulled'): updated server models f32[B, d] and node views
+      f32[B, P, d].
+    """
+    w, pulled = state["w"], state["pulled"]
+    B = w.shape[0]
+    diff = pulled - params["w_true"][:, None, :]
+    W = DATA_PLANE_BLOCK
+    Bp = -(-B // W) * W
+
+    def pad(a):
+        return a if Bp == B else jnp.concatenate(
+            [a, jnp.zeros((Bp - B,) + a.shape[1:], a.dtype)], axis=0)
+
+    d_p, f_p, s_p = pad(diff), pad(fin), pad(start)
+    w_p, pu_p = pad(w), pad(pulled)
+    lr_p, ns_p = pad(params["lr"]), pad(params["noise_std"])
+    blocks = [_data_plane_block(rand["X"], d_p[i:i + W], f_p[i:i + W],
+                                s_p[i:i + W], w_p[i:i + W], pu_p[i:i + W],
+                                lr_p[i:i + W], ns_p[i:i + W], rand["mb"])
+              for i in range(0, Bp, W)]
+    return (jnp.concatenate([b[0] for b in blocks])[:B],
+            jnp.concatenate([b[1] for b in blocks])[:B])
+
 
 #: carried tick state, in canonical order (control plane, then data plane)
 STATE_KEYS = ("steps", "alive", "computing", "event_time", "ready",
@@ -310,31 +350,8 @@ def psp_tick_ref(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
             state["pol_ema"])
 
     # 4. data plane: masked SGD push of every finisher, then the starters
-    #    pull the updated server model into their view.  The fin mask
-    #    zeroes non-finisher residuals, so frozen/inactive rows see
-    #    w − lr·0 — exactly w.  Executed in fixed-width row blocks
-    #    (:data:`DATA_PLANE_BLOCK`): the GEMM shapes never follow the
-    #    batch/shard width, so each row's bits are independent of how
-    #    rows are grouped — the sharded-sweep bit-identity invariant.
-    X, mbn = rand["X"], rand["mb"]
-    w, pulled = state["w"], state["pulled"]
-    diff = pulled - params["w_true"][:, None, :]
-    W = DATA_PLANE_BLOCK
-    Bp = -(-B // W) * W
-
-    def pad(a):
-        return a if Bp == B else jnp.concatenate(
-            [a, jnp.zeros((Bp - B,) + a.shape[1:], a.dtype)], axis=0)
-
-    d_p, f_p, s_p = pad(diff), pad(fin), pad(start)
-    w_p, pu_p = pad(w), pad(pulled)
-    lr_p, ns_p = pad(params["lr"]), pad(params["noise_std"])
-    blocks = [_data_plane_block(X, d_p[i:i + W], f_p[i:i + W],
-                                s_p[i:i + W], w_p[i:i + W], pu_p[i:i + W],
-                                lr_p[i:i + W], ns_p[i:i + W], mbn)
-              for i in range(0, Bp, W)]
-    w = jnp.concatenate([b[0] for b in blocks])[:B]
-    pulled = jnp.concatenate([b[1] for b in blocks])[:B]
+    #    pull the updated server model into their view (:func:`_data_plane`)
+    w, pulled = _data_plane(state, rand, params, fin, start)
 
     new_state = {"steps": steps, "alive": alive, "computing": computing,
                  "event_time": event_time, "ready": ready,
@@ -605,8 +622,42 @@ def psp_tick_sharded(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
 
 
 # --------------------------------------------------------------------------- #
-# Pallas kernel (one grid step per row block)
+# Pallas kernel: the control plane, one grid step per row block
 # --------------------------------------------------------------------------- #
+#: scenario rows per kernel grid step: the TPU's sublane tile, so every
+#: ``(rows, P)`` block satisfies Mosaic's (8, 128) block rule
+KERNEL_ROWS = 8
+
+#: peers per step of the β-sample rank test: the test streams the peer
+#: axis through ``(chunk, P)`` tiles, so its transients stay a few MB at
+#: paper scale (P = 1000) instead of ``(rows, P, P)``
+PEER_CHUNK = 128
+
+#: scoped-VMEM ceiling for one kernel instance (v5e has 128 MiB per
+#: core); a shape whose estimate exceeds it is refused at trace time
+VMEM_BUDGET = 96 << 20
+
+
+def _peer_chunking(P: int) -> Tuple[int, int]:
+    """(chunk, padded peer count) for the rank test's peer stream."""
+    chunk = min(PEER_CHUNK, -(-P // 8) * 8)
+    return chunk, -(-P // chunk) * chunk
+
+
+def _vmem_estimate(P: int, k_max: int, masked: bool) -> int:
+    """Bytes of VMEM the kernel needs at node count ``P`` (an upper
+    estimate: double-buffered blocks plus the rank test's live tiles)."""
+    lanes = -(-P // 128) * 128
+    block = KERNEL_ROWS * lanes * 4                 # one (rows, P) tile
+    total = 2 * 20 * block + 40 * block             # I/O blocks + values
+    chunk, Pp = _peer_chunking(P)
+    if k_max > 1 or masked:
+        total += 2 * (KERNEL_ROWS if masked else 1) * Pp * lanes * 4
+    if k_max > 0:
+        total += 12 * chunk * lanes * 4
+    return total
+
+
 def _first_argmax_rows(scores: jax.Array, mask: jax.Array,
                        iota: jax.Array, P: int) -> jax.Array:
     """Per-row index of the first maximum of ``scores`` under ``mask``.
@@ -621,20 +672,102 @@ def _first_argmax_rows(scores: jax.Array, mask: jax.Array,
     return jnp.min(jnp.where(s == mx, iota, P), axis=1, keepdims=True)
 
 
+def _any(x: jax.Array, axis: int) -> jax.Array:
+    """``jnp.any`` as an i32 max (Mosaic reduces i32, not i1, vectors)."""
+    return jnp.max(x.astype(jnp.int32), axis=axis, keepdims=True) > 0
+
+
+def _peer_col(row: jax.Array, j0, n: int) -> jax.Array:
+    """Entries ``j0 … j0+n−1`` of a ``(1, P)`` i32 lane vector as an
+    ``(n, 1)`` column (0 past P): select + lane sum, exact for integers
+    and free of transposes, gathers and matmuls."""
+    P = row.shape[1]
+    jj = lax.broadcasted_iota(jnp.int32, (n, P), 0) + j0
+    pp = lax.broadcasted_iota(jnp.int32, (n, P), 1)
+    return jnp.sum(jnp.where(jj == pp, row, 0), axis=1, keepdims=True)
+
+
+def _rank_test_row(s_row, st_row, a_row, scores, P: int, Pp: int,
+                   chunk: int):
+    """β-sample rank test of one scenario row, streamed over peer chunks.
+
+    ``s_row``/``st_row`` (1, P) i32: steps and effective staleness of the
+    deciding nodes (lanes); ``a_row`` (1, P) i32 alive mask (masked
+    rows) or None; ``scores(j0)`` → the ``(chunk, P)`` tile of
+    transposed scores ``[peer, decider]``.  Pass 1 finds each decider's
+    lowest-(score, index) bad peer — a lexicographic min merged across
+    chunks; pass 2 counts the eligible peers ordered before it.  Returns
+    ``(any_bad, cnt, n_elig)`` (1, P) — the rank form of the top-k
+    sample: the bad peer is sampled iff ``cnt < β``.
+    """
+    i32 = jnp.int32
+    pp = lax.broadcasted_iota(i32, (chunk, P), 1)
+    # a peer lags too far iff its step < the decider's step − staleness
+    thr = s_row - st_row
+
+    def tile(c):
+        j0 = pl.multiple_of(c * chunk, chunk)
+        jj = lax.broadcasted_iota(i32, (chunk, P), 0) + j0
+        elig = (jj != pp) & (jj < P)
+        if a_row is not None:
+            elig = elig & (_peer_col(a_row, j0, chunk) != 0)
+        bad = elig & (_peer_col(s_row, j0, chunk) < thr)
+        return jj, elig, bad, scores(j0)
+
+    def pass1(c, carry):
+        mbs, mbi, nbad = carry
+        jj, _, bad, sc = tile(c)
+        cm = jnp.min(jnp.where(bad, sc, 3.0), axis=0, keepdims=True)
+        ci = jnp.min(jnp.where(bad & (sc == cm), jj, P), axis=0,
+                     keepdims=True)
+        mbi = jnp.where(cm < mbs, ci,
+                        jnp.where(cm == mbs, jnp.minimum(mbi, ci), mbi))
+        return (jnp.minimum(mbs, cm), mbi,
+                nbad + jnp.sum(bad.astype(i32), axis=0, keepdims=True))
+
+    n = Pp // chunk
+    mbs, mbi, nbad = lax.fori_loop(
+        0, n, pass1, (jnp.full((1, P), 3.0, jnp.float32),
+                      jnp.full((1, P), P, i32), jnp.zeros((1, P), i32)))
+
+    def pass2(c, carry):
+        cnt, nel = carry
+        jj, elig, _, sc = tile(c)
+        before = elig & ((sc < mbs) | ((sc == mbs) & (jj < mbi)))
+        return (cnt + jnp.sum(before.astype(i32), axis=0, keepdims=True),
+                nel + jnp.sum(elig.astype(i32), axis=0, keepdims=True))
+
+    cnt, n_elig = lax.fori_loop(
+        0, n, pass2, (jnp.zeros((1, P), i32), jnp.zeros((1, P), i32)))
+    return nbad > 0, cnt, n_elig
+
+
+def _peer_step_row(s_row, take, P: int, Pp: int, chunk: int):
+    """``s_row[take]`` for a ``(1, P)`` index vector: the β = 1 peer's
+    step, as a select + sublane sum over peer chunks (exact, no gather)."""
+    i32 = jnp.int32
+
+    def body(c, acc):
+        j0 = pl.multiple_of(c * chunk, chunk)
+        jj = lax.broadcasted_iota(i32, (chunk, P), 0) + j0
+        return acc + jnp.sum(
+            jnp.where(jj == take, _peer_col(s_row, j0, chunk), 0),
+            axis=0, keepdims=True)
+
+    return lax.fori_loop(0, Pp // chunk, body, jnp.zeros((1, P), i32))
+
+
 def _tick_kernel(*refs, k_max: int, has_churn: bool, masked: bool,
-                 use_u1: bool, adaptive: bool, W: int, P: int, d: int,
-                 m: int):
-    """Kernel body: one W-row block's full tick in VMEM."""
+                 use_u1: bool, adaptive: bool, W: int, P: int):
+    """Kernel body: one W-row block's control-plane tick in VMEM."""
     it = iter(refs)
     steps_ref, alive_ref, computing_ref, event_ref, ready_ref, blocked_ref,\
         pl_ref, pj_ref = (next(it) for _ in range(8))
-    w_ref, pulled_ref = next(it), next(it)
     ln_ref, jn_ref = next(it), next(it)
     u_dur_ref = next(it)
     samp_ref = next(it) if (k_max > 0) else None
     ul_ref = next(it) if has_churn else None
     uj_ref = next(it) if has_churn else None
-    x_ref, mb_ref = next(it), next(it)
     ct_ref, vs_ref = next(it), next(it)
     stal_ref, beta_ref, asp_ref, fv_ref, sm_ref, dh_ref = \
         (next(it) for _ in range(6))
@@ -644,11 +777,10 @@ def _tick_kernel(*refs, k_max: int, has_churn: bool, masked: bool,
         dssp_ref, ebsp_ref, ann_ref, lo_ref, blo_ref = \
             (next(it) for _ in range(5))
         ebr_ref, eba_ref = next(it), next(it)
-    wt_ref, lr_ref, ns_ref, hz_ref = (next(it) for _ in range(4))
+    hz_ref = next(it)
     t_ref, eps_ref, poll_ref = next(it), next(it), next(it)
     (o_steps, o_alive, o_comp, o_event, o_ready, o_block, o_pl, o_pj,
-     o_w, o_pulled, o_fin, o_start, o_nfin, o_ctrl) = \
-        (next(it) for _ in range(14))
+     o_fin, o_start, o_nfin, o_ctrl) = (next(it) for _ in range(12))
     if adaptive:
         o_thr, o_ema, o_beta = (next(it) for _ in range(3))
 
@@ -660,12 +792,11 @@ def _tick_kernel(*refs, k_max: int, has_churn: bool, masked: bool,
     ready = ready_ref[...]
     blocked = blocked_ref[...] != 0
     valid_slot = vs_ref[...] != 0
-    t = t_ref[0, 0]
-    eps, poll = eps_ref[0, 0], poll_ref[0, 0]
+    # (1, 1) operands broadcast; no scalar extraction from vector memory
+    t, eps, poll = t_ref[...], eps_ref[...], poll_ref[...]
     stal, beta = stal_ref[...], beta_ref[...]   # (W, 1) i32
     active = t <= hz_ref[...] + eps             # (W, 1) row liveness
-    iota = jax.lax.broadcasted_iota(i32, (1, P), 1)
-    jj = jax.lax.broadcasted_iota(i32, (P, P), 1)
+    iota = lax.broadcasted_iota(i32, (1, P), 1)
 
     # 0. churn: one pre-sampled leave/join per row per tick
     if has_churn:
@@ -676,7 +807,7 @@ def _tick_kernel(*refs, k_max: int, has_churn: bool, masked: bool,
         vid = _first_argmax_rows(ul_ref[...], alive, iota, P)
         alive = alive & ~(do_l & (iota == vid))
         pool = ~alive & valid_slot
-        do_j = active & (pend_j > 0) & jnp.any(pool, axis=1, keepdims=True)
+        do_j = active & (pend_j > 0) & _any(pool, 1)
         jid = _first_argmax_rows(uj_ref[...], pool, iota, P)
         sel = do_j & (iota == jid)
         alive = alive | sel
@@ -695,11 +826,11 @@ def _tick_kernel(*refs, k_max: int, has_churn: bool, masked: bool,
 
     # 1. finishes
     fin = computing & alive & (event_time <= t + eps) & active
-    any_fin = jnp.any(fin, axis=1, keepdims=True)
+    any_fin = _any(fin, 1)
     row_last = jnp.max(jnp.where(fin, event_time, -jnp.inf), axis=1,
                        keepdims=True)
     row_unblock = jnp.where(any_fin, jnp.minimum(row_last, t), t)
-    steps = steps + fin
+    steps = steps + fin.astype(i32)
     computing = computing & ~fin
     ready = jnp.where(fin, event_time, ready)
     blocked = blocked & ~fin
@@ -720,57 +851,64 @@ def _tick_kernel(*refs, k_max: int, has_churn: bool, masked: bool,
         stal_eff = jnp.where(is_dssp, thr_ref[...],
                              jnp.where(is_ebsp, slack, stal))   # (W, P)
         beta_eff = jnp.where(is_ann, pbeta_ref[...], beta)      # (W, 1)
+    stal_eff = jnp.broadcast_to(stal_eff, (W, P))
     min_alive = jnp.min(jnp.where(alive, steps, _I32_MAX), axis=1,
                         keepdims=True)
     pass_fv = steps - min_alive <= stal_eff
     if k_max == 0:
-        pass_sm = jnp.ones((W, P), dtype=bool)
+        fail_sm = None
         n_sampled = jnp.zeros((W, P), dtype=i32)
-    elif use_u1:
-        # β = 1 fast path: one shared uniform over the P−1 non-self
-        # slots, the exact formula of sample_peer_indices_jax's k == 1
-        # branch.  The peer's step is fetched with a one-hot matmul —
-        # exact for counters below 2²⁴, a single small dot instead of a
-        # (W, P, P) mask pipeline, and gather-free for the TPU MXU.
-        draw = jnp.floor(samp_ref[...] * max(P - 1, 1)).astype(i32)
-        take = jnp.minimum(draw + (draw >= iota), P - 1)       # (1, P)
-        oh = (jnp.reshape(take, (P, 1)) == jj).astype(jnp.float32)
-        step_peer = jax.lax.dot_general(
-            steps.astype(jnp.float32), oh,
-            (((1,), (1,)), ((), ()))).astype(i32)              # (W, P)
-        lag_bad = steps - step_peer > stal_eff
-        ok = (P - 1 >= 1) & (beta_eff >= 1)                    # (W, 1)
-        pass_sm = ~lag_bad | ~ok
-        n_sampled = jnp.broadcast_to(
-            jnp.minimum(beta_eff, P - 1), (W, P)).astype(i32)
     else:
-        # rank form of the top-k β-sample: the lowest-(score, index) bad
-        # peer is inside the sample iff fewer than β eligible peers
-        # precede it — identical to lax.top_k selection, fused, no gather
-        sc = samp_ref[...]                      # (W, P, P) or (1, P, P)
-        ii = jax.lax.broadcasted_iota(i32, (P, P), 0)
-        # the shared-draw fast path (masked=False) matches the unmasked
-        # reference primitive: every non-self peer is in the pool — the
-        # sweep engine only takes it when the whole batch is fully alive
-        eligible = (jj != ii)[None]                            # (1, P, P)
-        if masked:
-            eligible = eligible & alive[:, None, :]            # (W, P, P)
-        lag = steps[:, :, None] - steps[:, None, :]
-        bad = eligible & (lag > stal_eff[:, :, None])          # (W, P, P)
-        any_bad = jnp.any(bad, axis=2)
-        mbs = jnp.min(jnp.where(bad, sc, 3.0), axis=2, keepdims=True)
-        mbi = jnp.min(jnp.where(bad & (sc == mbs), jj[None], P), axis=2,
-                      keepdims=True)
-        before = eligible & ((sc < mbs) | ((sc == mbs) & (jj[None] < mbi)))
-        cnt = jnp.sum(before.astype(i32), axis=2)              # (W, P)
-        fail_sm = any_bad & (cnt < beta_eff)
-        pass_sm = ~fail_sm
-        n_elig = jnp.sum(
-            jnp.broadcast_to(eligible, (W, P, P)).astype(i32), axis=2)
-        n_sampled = jnp.minimum(beta_eff, n_elig)
+        # per-row β-sample tests, assembled back into (W, P) by row select
+        chunk, Pp = _peer_chunking(P)
+        rows = lax.broadcasted_iota(i32, (W, P), 0)
+        alive_i = alive.astype(i32)
+        if use_u1:
+            # β = 1 fast path: one shared uniform over the P−1 non-self
+            # slots, the exact formula of sample_peer_indices_jax's
+            # k == 1 branch; the peer's step by select (exact)
+            draw = jnp.floor(samp_ref[...] * max(P - 1, 1)).astype(i32)
+            take = jnp.minimum(draw + (draw >= iota).astype(i32), P - 1)
+            step_peer = jnp.zeros((W, P), i32)
+            for r in range(W):
+                step_peer = jnp.where(
+                    rows == r, _peer_step_row(steps[r:r + 1], take, P, Pp,
+                                              chunk), step_peer)
+            # the one peer is consulted iff min(β, P − 1) ≥ 1
+            fail_sm = (steps - step_peer > stal_eff) \
+                & (jnp.minimum(beta_eff, P - 1) >= 1)
+            n_sampled = jnp.broadcast_to(
+                jnp.minimum(beta_eff, P - 1), (W, P)).astype(i32)
+        else:
+            # rank form of the top-k β-sample: the lowest-(score, index)
+            # bad peer is inside the sample iff fewer than β eligible
+            # peers precede it — identical to lax.top_k selection.  The
+            # shared-draw path (masked=False) matches the unmasked
+            # reference primitive: every non-self peer is in the pool —
+            # the sweep engine only takes it when the batch is fully alive
+            any_bad = jnp.zeros((W, P), i32)
+            cnt = jnp.zeros((W, P), i32)
+            n_elig = jnp.zeros((W, P), i32)
+            for r in range(W):
+                if masked:
+                    sc = lambda j0, r=r: samp_ref[r, pl.ds(j0, chunk), :]
+                else:
+                    sc = lambda j0: samp_ref[pl.ds(j0, chunk), :]
+                ab, c, ne = _rank_test_row(
+                    steps[r:r + 1], stal_eff[r:r + 1],
+                    alive_i[r:r + 1] if masked else None, sc, P, Pp, chunk)
+                here = rows == r
+                any_bad = jnp.where(here, ab.astype(i32), any_bad)
+                cnt = jnp.where(here, c, cnt)
+                n_elig = jnp.where(here, ne, n_elig)
+            fail_sm = (any_bad != 0) & (cnt < beta_eff)
+            n_sampled = jnp.minimum(beta_eff, n_elig)
     is_asp, full_view = asp_ref[...] != 0, fv_ref[...] != 0    # (W, 1)
-    passed = jnp.where(is_asp, True,
-                       jnp.where(full_view, pass_fv, pass_sm))
+    # where(asp, True, where(fv, pass_fv, pass_sm)) in boolean algebra
+    # (Mosaic has no select over i1 vectors)
+    passed = is_asp | (full_view & pass_fv)
+    passed = passed | ~full_view if fail_sm is None \
+        else passed | (~full_view & ~fail_sm)
     o_ctrl[...] = jnp.sum(jnp.where(cand, n_sampled * dh_ref[...], 0),
                           axis=1, keepdims=True)
 
@@ -796,8 +934,7 @@ def _tick_kernel(*refs, k_max: int, has_churn: bool, masked: bool,
                       keepdims=True)
         mns = jnp.min(jnp.where(alive, steps, _I32_MAX), axis=1,
                       keepdims=True)
-        gap = jnp.where(jnp.any(alive, axis=1, keepdims=True),
-                        mxs - mns, 0)                          # (W, 1)
+        gap = jnp.where(_any(alive, 1), mxs - mns, 0)          # (W, 1)
         o_thr[...] = jnp.where(
             is_dssp & active,
             jnp.clip(gap, lo_ref[...], stal),
@@ -811,52 +948,15 @@ def _tick_kernel(*refs, k_max: int, has_churn: bool, masked: bool,
                                (1.0 - al) * ema_ref[...] + al * dur,
                                ema_ref[...])
 
-    # 4. data plane: the block's SGD push + model-view pull — literally
-    #    _data_plane_block, the same code the jnp reference runs, so the
-    #    two impls match bit for bit.  All operands are VMEM resident;
-    #    the fin/start masks come straight from the phases above.
-    X = x_ref[...]                              # (P, m, d)
-    pulled = pulled_ref[...]                    # (W, P, d)
-    diff = pulled - wt_ref[...][:, None, :]     # view − ground truth
-    w_new, pulled_new = _data_plane_block(
-        X, diff, fin, start, w_ref[...], pulled,
-        jnp.reshape(lr_ref[...], (W,)), jnp.reshape(ns_ref[...], (W,)),
-        mb_ref[...])
-
     o_steps[...] = steps
     o_alive[...] = alive.astype(i32)
     o_comp[...] = computing.astype(i32)
     o_event[...] = event_time
     o_ready[...] = ready
     o_block[...] = blocked.astype(i32)
-    o_w[...] = w_new
-    o_pulled[...] = pulled_new
     o_fin[...] = fin.astype(i32)
     o_start[...] = start.astype(i32)
     o_nfin[...] = jnp.sum(fin.astype(i32), axis=1, keepdims=True)
-
-
-def _kernel_block_width(P: int, k_max: int, masked: bool,
-                        interpret: bool) -> int:
-    """Rows per kernel grid step.
-
-    Interpret/CPU always uses :data:`DATA_PLANE_BLOCK` — that makes the
-    kernel's data plane byte-identical to the jnp reference's blocks (and
-    keeps the interpreter's grid loop short).  On real TPU hardware the
-    (W, P, P) score/lag tiles bound W by VMEM: halve until the dominant
-    per-step buffers fit a ~8 MB budget (worst case W = 1, the PR-3
-    layout).
-    """
-    W = DATA_PLANE_BLOCK
-    if interpret:
-        return W
-    # the β = 1 shared-u1 path carries only a W-independent (P, P)
-    # one-hot plus (W, P) buffers; per-row P² tiles exist only for the
-    # rank form (k_max > 1) and the per-row masked scores
-    per_row = 4 * (P * P if k_max > 1 or masked else P)
-    while W > 1 and W * per_row > (8 << 20):
-        W //= 2
-    return W
 
 
 def psp_tick_tpu(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
@@ -865,24 +965,35 @@ def psp_tick_tpu(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
                  k_max: int, has_churn: bool, masked: bool,
                  adaptive: bool = False, interpret: bool = False,
                  ) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
-    """Fused Pallas tick: same contract as :func:`psp_tick_ref`.
+    """Pallas tick: same contract as :func:`psp_tick_ref`.
 
-    Grid = (⌈B/W⌉,): each grid step owns one W-row block of scenarios —
-    its ``(W, P)`` state slices, its ``(W, P, d)`` model views, its score
-    tiles (or the shared tile when the whole batch reuses one draw), the
-    shared minibatch blob, and its ``(W, 1)`` policy columns.  W is
-    :data:`DATA_PLANE_BLOCK` in interpret mode (bit-identical to the
-    reference's data-plane blocks) and VMEM-clamped on real TPUs; batches
-    pad up to a W multiple with inert rows (negative horizon).  Booleans
-    travel as i32 (TPU-friendly); the wrapper restores dtypes.
+    The kernel runs the control plane — grid = (⌈B/8⌉,), each step owns
+    one :data:`KERNEL_ROWS`-row block's ``(8, P)`` state slices, its
+    score tiles (or the shared tile when the whole batch reuses one
+    draw) and its ``(8, 1)`` policy columns; batches pad up with inert
+    rows (negative horizon).  Booleans travel as i32 and scores as the
+    transposed ``[peer, decider]`` tile, padded to whole peer chunks.
+    The data plane then runs as the reference's own XLA code
+    (:func:`_data_plane`) on the kernel's ``fin``/``start`` masks.
+
+    Raises:
+      ValueError: at trace time, when ``P`` needs more VMEM than
+        :data:`VMEM_BUDGET` (e.g. a 100k-node fleet) — the caller picks
+        ``impl="ref"`` for such fleets explicitly.
     """
     B, P = state["steps"].shape
-    d = state["w"].shape[-1]
-    m = rand["X"].shape[1]
     i32, f32 = jnp.int32, jnp.float32
     use_u1 = k_max == 1 and not masked
-    W = _kernel_block_width(P, k_max, masked, interpret)
+    W = KERNEL_ROWS
     Bp = -(-B // W) * W
+    need = _vmem_estimate(P, k_max, masked)
+    if need > VMEM_BUDGET:
+        raise ValueError(
+            f"psp_tick kernel: P={P} nodes (k_max={k_max}, masked={masked})"
+            f" needs ~{need >> 20} MiB of VMEM, over the "
+            f"{VMEM_BUDGET >> 20} MiB budget; run this fleet with "
+            "impl='ref'")
+    chunk, Pp = _peer_chunking(P)
 
     def pad(a, fill=0):
         a = jnp.asarray(a)
@@ -891,17 +1002,16 @@ def psp_tick_tpu(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
         filler = jnp.full((Bp - B,) + a.shape[1:], fill, a.dtype)
         return jnp.concatenate([a, filler], axis=0)
 
-    def row(a, dtype=None):
-        a = pad(jnp.asarray(a) if dtype is None
-                else jnp.asarray(a).astype(dtype))
-        return a, pl.BlockSpec((W, P), lambda b: (b, 0))
+    def row(a, dtype):
+        return pad(jnp.asarray(a).astype(dtype)), \
+            pl.BlockSpec((W, P), lambda b: (b, 0))
 
     def col(a, dtype=i32, fill=0):
         return pad(jnp.asarray(a, dtype), fill).reshape(Bp, 1), \
             pl.BlockSpec((W, 1), lambda b: (b, 0))
 
-    def scalar(a, dtype=f32):
-        return jnp.asarray(a, dtype).reshape(1, 1), \
+    def scalar(a):
+        return jnp.asarray(a, f32).reshape(1, 1), \
             pl.BlockSpec((1, 1), lambda b: (0, 0))
 
     inputs, specs = [], []
@@ -918,31 +1028,26 @@ def psp_tick_tpu(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
     push(row(state["blocked"], i32))
     push(col(state["pend_leave"]))
     push(col(state["pend_join"]))
-    inputs.append(pad(jnp.asarray(state["w"], f32)))
-    specs.append(pl.BlockSpec((W, d), lambda b: (b, 0)))
-    inputs.append(pad(jnp.asarray(state["pulled"], f32)))
-    specs.append(pl.BlockSpec((W, P, d), lambda b: (b, 0, 0)))
     push(col(leave_n))
     push(col(join_n))
     push(row(rand["dur"], f32))
     if k_max > 0:
         if use_u1:
-            u1 = jnp.asarray(rand["u1"], f32).reshape(1, P)
-            inputs.append(u1)
+            inputs.append(jnp.asarray(rand["u1"], f32).reshape(1, P))
             specs.append(pl.BlockSpec((1, P), lambda b: (0, 0)))
-        elif masked:
-            inputs.append(pad(jnp.asarray(rand["scores"], f32)))
-            specs.append(pl.BlockSpec((W, P, P), lambda b: (b, 0, 0)))
         else:
-            inputs.append(jnp.asarray(rand["scores"], f32).reshape(1, P, P))
-            specs.append(pl.BlockSpec((1, P, P), lambda b: (0, 0, 0)))
+            sc_t = jnp.swapaxes(jnp.asarray(rand["scores"], f32), -1, -2)
+            pad_peers = [(0, 0)] * (sc_t.ndim - 2) + [(0, Pp - P), (0, 0)]
+            sc_t = jnp.pad(sc_t, pad_peers)
+            if masked:
+                inputs.append(pad(sc_t))
+                specs.append(pl.BlockSpec((W, Pp, P), lambda b: (b, 0, 0)))
+            else:
+                inputs.append(sc_t)
+                specs.append(pl.BlockSpec((Pp, P), lambda b: (0, 0)))
     if has_churn:
         push(row(rand["leave"], f32))
         push(row(rand["join"], f32))
-    inputs.append(jnp.asarray(rand["X"], f32))
-    specs.append(pl.BlockSpec((P, m, d), lambda b: (0, 0, 0)))
-    inputs.append(jnp.asarray(rand["mb"], f32))
-    specs.append(pl.BlockSpec((P, m), lambda b: (0, 0)))
     push(row(params["compute_time"], f32))
     push(row(params["valid_slot"], i32))
     push(col(params["staleness"]))
@@ -965,10 +1070,6 @@ def psp_tick_tpu(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
         push(col(params["beta_lo"]))
         push(col(params["ebsp_range"], f32))
         push(col(params["ebsp_alpha"], f32))
-    inputs.append(pad(jnp.asarray(params["w_true"], f32)))
-    specs.append(pl.BlockSpec((W, d), lambda b: (b, 0)))
-    push(col(params["lr"], f32))
-    push(col(params["noise_std"], f32))
     # padded rows freeze: a negative horizon keeps them inert forever
     push(col(params["horizon"], f32, fill=-1.0))
     push(scalar(t))
@@ -977,46 +1078,46 @@ def psp_tick_tpu(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
 
     rp = lambda dt: jax.ShapeDtypeStruct((Bp, P), dt)
     cp = lambda: jax.ShapeDtypeStruct((Bp, 1), i32)
+    row_spec = pl.BlockSpec((W, P), lambda b: (b, 0))
+    col_spec = pl.BlockSpec((W, 1), lambda b: (b, 0))
     out_shape = [rp(i32), rp(i32), rp(i32), rp(f32), rp(f32), rp(i32),
-                 cp(), cp(),
-                 jax.ShapeDtypeStruct((Bp, d), f32),
-                 jax.ShapeDtypeStruct((Bp, P, d), f32),
-                 rp(i32), rp(i32), cp(), cp()]
-    out_specs = ([pl.BlockSpec((W, P), lambda b: (b, 0))] * 6
-                 + [pl.BlockSpec((W, 1), lambda b: (b, 0))] * 2
-                 + [pl.BlockSpec((W, d), lambda b: (b, 0)),
-                    pl.BlockSpec((W, P, d), lambda b: (b, 0, 0))]
-                 + [pl.BlockSpec((W, P), lambda b: (b, 0))] * 2
-                 + [pl.BlockSpec((W, 1), lambda b: (b, 0))] * 2)
+                 cp(), cp(), rp(i32), rp(i32), cp(), cp()]
+    out_specs = [row_spec] * 6 + [col_spec] * 2 + [row_spec] * 2 \
+        + [col_spec] * 2
     if adaptive:
         out_shape += [cp(), rp(f32), cp()]      # pol_thr, pol_ema, pol_beta
-        out_specs += [pl.BlockSpec((W, 1), lambda b: (b, 0)),
-                      pl.BlockSpec((W, P), lambda b: (b, 0)),
-                      pl.BlockSpec((W, 1), lambda b: (b, 0))]
+        out_specs += [col_spec, row_spec, col_spec]
 
+    extra = {} if interpret else dict(compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=min(max(2 * need, 32 << 20), 120 << 20)))
     outs = pl.pallas_call(
         functools.partial(_tick_kernel, k_max=k_max, has_churn=has_churn,
                           masked=masked, use_u1=use_u1, adaptive=adaptive,
-                          W=W, P=P, d=d, m=m),
+                          W=W, P=P),
         grid=(Bp // W,),
         in_specs=specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="psp_tick",
+        **extra,
     )(*inputs)
 
     outs = [o[:B] for o in outs]
     (steps, alive, computing, event_time, ready, blocked, pend_l, pend_j,
-     w, pulled, fin, start, n_fin, ctrl) = outs[:14]
+     fin, start, n_fin, ctrl) = outs[:12]
+    fin, start = fin != 0, start != 0
+    w, pulled = _data_plane(state, rand, params, fin, start)
     new_state = {"steps": steps, "alive": alive != 0,
                  "computing": computing != 0, "event_time": event_time,
                  "ready": ready, "blocked": blocked != 0,
                  "pend_leave": pend_l[:, 0], "pend_join": pend_j[:, 0],
                  "w": w, "pulled": pulled}
     if adaptive:
-        pol_thr, pol_ema, pol_beta = outs[14:]
+        pol_thr, pol_ema, pol_beta = outs[12:]
         new_state.update(pol_thr=pol_thr[:, 0], pol_ema=pol_ema,
                          pol_beta=pol_beta[:, 0])
-    out = {"fin": fin != 0, "start": start != 0, "n_fin": n_fin[:, 0],
+    out = {"fin": fin, "start": start, "n_fin": n_fin[:, 0],
            "ctrl": ctrl[:, 0]}
     return new_state, out

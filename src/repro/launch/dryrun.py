@@ -167,10 +167,7 @@ def run_combo(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
                 compiled = lowered.compile()
                 t_compile = time.time() - t0 - t_lower
         ma = compiled.memory_analysis()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # per-device list on newer jax
-            cost = cost[0]
-        cost = dict(cost)
+        cost = dict(compiled.cost_analysis())
         hlo = compiled.as_text()
         coll = collective_bytes(hlo)
         # trip-count-aware analysis (cost_analysis counts while bodies once)

@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced as make_reduced
+from repro.core.compile_cache import enable_compile_cache
 from repro.models import init_model
 from repro.serving import (InferenceServer, Request, ServeConfig,
                            ServingEngine, SnapshotWatcher)
@@ -78,6 +79,7 @@ def main(argv=None) -> int:
     ap.add_argument("--timeout", type=float, default=600.0,
                     help="live mode: per-request completion timeout (s)")
     a = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(a.arch)
     if a.reduced:

@@ -41,10 +41,13 @@ import dataclasses
 import time
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.checkpoint import (CheckpointManager, CheckpointPolicy,
                               latest_step, restore_checkpoint)
 from repro.configs import get_config, reduced as make_reduced
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.spmd_psp import (PSPConfig, psp_init, psp_train_step,
                                  state_from_tree, state_to_tree)
 from repro.data import SyntheticLM
@@ -80,7 +83,46 @@ def _maybe_resume(a, template):
     return tree, step
 
 
+def make_psp_step(cfg, pcfg: PSPConfig, opt):
+    """The jitted PSP tick ``(state, tokens[W, b, s]) -> (state, metrics)``
+    for model ``cfg`` under barrier config ``pcfg``, with the carried
+    ``PSPState`` donated."""
+    def grad_fn(p, tokens):
+        (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
+            p, {"tokens": tokens}, cfg)
+        return loss, clip_by_norm(g, 1.0)
+
+    return jax.jit(lambda s, b: psp_train_step(pcfg, grad_fn, opt.update,
+                                               s, b), donate_argnums=(0,))
+
+
+def _fingerprint(params) -> np.ndarray:
+    """Per-leaf f32 sums of squares on the host: a few bytes that tell
+    whether a step moved the params, without a second device copy."""
+    return np.asarray(jax.device_get(
+        [jnp.sum(jnp.square(x.astype(jnp.float32)))
+         for x in jax.tree.leaves(params)]))
+
+
 def main(argv=None) -> int:
+    """Command-line entry point: :func:`run`, exit code 0."""
+    run(argv)
+    return 0
+
+
+def run(argv=None) -> dict:
+    """Train with the command-line flags in ``argv``.
+
+    Each step's jitted function donates the carried training state (pjit:
+    params and optimizer state; PSP: the whole ``PSPState``), so XLA
+    updates it in place instead of holding a second copy.  Whatever reads
+    the state after a step — checkpoints, serving snapshots — takes its
+    host copy synchronously, before the next step consumes the buffers.
+
+    Returns a report: per-step ``loss`` and ``pushes`` (landed gradient
+    pushes; the global batch count in pjit mode), and per-leaf param
+    fingerprints ``params_before``/``params_after`` (:func:`_fingerprint`).
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--reduced", action="store_true")
@@ -119,6 +161,7 @@ def main(argv=None) -> int:
     ap.add_argument("--publish-every", type=int, default=25,
                     help="snapshot-publication step cadence")
     a = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(a.arch)
     if a.reduced:
@@ -129,6 +172,8 @@ def main(argv=None) -> int:
     params = init_model(cfg, key)
     n_params = sum(x.size for x in jax.tree.leaves(params))
     print(f"arch={cfg.name} params={n_params:,} barrier={a.barrier}")
+    params_before = _fingerprint(params)
+    losses, pushes = [], []
 
     mgr = _make_manager(a)
     pub = (SnapshotPublisher(a.publish_dir, every_steps=a.publish_every)
@@ -143,10 +188,12 @@ def main(argv=None) -> int:
         params, state = tree["params"], tree["opt_state"]
         for _ in range(start):       # replay the consumed data stream
             next(data)
-        step_fn = jax.jit(make_train_step(cfg, opt))
+        step_fn = jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1))
         for t in range(start, a.steps):
             batch = next(data)
             params, state, loss, _ = step_fn(params, state, batch)
+            losses.append(loss)
+            pushes.append(1)
             if t % a.log_every == 0 or t == a.steps - 1:
                 print(f"step {t:5d} loss {float(loss):.4f} "
                       f"({time.time()-t0:.1f}s)")
@@ -166,21 +213,18 @@ def main(argv=None) -> int:
                          sample_size=a.sample_size, staleness=a.staleness,
                          straggler_frac=a.straggler_frac)
 
-        def grad_fn(p, tokens):
-            (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                p, {"tokens": tokens}, cfg)
-            return loss, clip_by_norm(g, 1.0)
-
         st = psp_init(pcfg, params, opt.init, jax.random.fold_in(key, 1))
         tree, start = _maybe_resume(a, state_to_tree(st))
         st = state_from_tree(tree)
         for _ in range(start):       # replay the consumed data stream
             next(data)
-        step_fn = jax.jit(lambda s, b: psp_train_step(
-            pcfg, grad_fn, opt.update, s, b))
+        step_fn = make_psp_step(cfg, pcfg, opt)
+        del params, tree             # st owns the buffers from here on
         for t in range(start, a.steps):
             toks = next(data)["tokens"].reshape(W, a.batch, a.seq)
             st, m = step_fn(st, toks)
+            losses.append(m["loss"])
+            pushes.append(m["pushes"])
             if t % a.log_every == 0 or t == a.steps - 1:
                 print(f"tick {t:5d} loss {float(m['loss']):.4f} "
                       f"vtime {float(m['virtual_time']):.2f}s "
@@ -207,7 +251,10 @@ def main(argv=None) -> int:
             pub.publish(a.steps, params, meta, block=True)
         pub.close()
         print(f"published {pub.published} snapshots to {a.publish_dir}")
-    return 0
+    return {"loss": [float(x) for x in jax.device_get(losses)],
+            "pushes": [int(x) for x in jax.device_get(pushes)],
+            "params_before": params_before,
+            "params_after": _fingerprint(params)}
 
 
 if __name__ == "__main__":

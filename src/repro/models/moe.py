@@ -29,11 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:                                   # top-level since jax 0.5
-    from jax import shard_map as _shard_map
-except ImportError:                    # jax ≤ 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.models.params import ParamDef
 from repro.parallel.sharding import constrain, current_rules
 
@@ -157,7 +152,7 @@ def moe_apply(p: dict, x: jax.Array, cfg,
             aux = jax.lax.psum(aux, axis_name=batch_axes) / dp
         return y.reshape(bl, S, D), aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_axes or None, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),

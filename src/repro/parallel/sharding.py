@@ -20,7 +20,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["AxisRules", "PSP_WORKER_AXES", "SWEEP_NODES_AXIS",
            "SWEEP_ROWS_AXIS", "constrain", "current_rules", "make_rules",
@@ -168,6 +168,10 @@ def make_rules(cfg, shape, mesh: Optional[Mesh]) -> AxisRules:
     data_axes: MeshAxes = ()
     model = 16
     if mesh is not None:
+        # the rules place arrays by sharding constraints, which only Auto
+        # mesh axes accept; jax.make_mesh gives Explicit axes by default
+        mesh = Mesh(mesh.devices, mesh.axis_names,
+                    axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         names = mesh.axis_names
         data_axes = tuple(a for a in ("pod", "data") if a in names)
         model = int(mesh.shape.get("model", 1))

@@ -355,3 +355,18 @@ def test_psp_tick_churn_sweep_impl_invariant(monkeypatch):
         np.testing.assert_allclose(a.errors, b.errors, rtol=0, atol=1e-6)
         assert a.total_updates == b.total_updates
         assert a.control_messages == b.control_messages
+
+
+def test_psp_tick_kernel_refuses_fleet_it_cannot_hold():
+    """A fleet over the kernel's VMEM budget (the 100k-node sweep) is
+    refused at trace time with an error naming P — never deep in Mosaic,
+    and never by a silent switch to the reference."""
+    import functools
+    import jax
+    from repro.kernels.psp_tick import psp_tick_tpu
+    state, rand, params, leave_n, join_n, masked = _tick_problem(
+        0, 2, 100_000, False, False, 1)
+    with pytest.raises(ValueError, match="P=100000"):
+        jax.eval_shape(functools.partial(
+            psp_tick_tpu, k_max=1, has_churn=False, masked=masked),
+            state, rand, params, np.float32(0.1), leave_n, join_n)

@@ -27,11 +27,7 @@ def test_scan_trip_count_multiplied():
     assert r.flops == 8 * 2 * 512 ** 2
     assert 8 in r.while_trips.values()
     # builtin cost_analysis counts the body once — document the gap
-    # (cost_analysis returns a per-device list on newer jax)
-    ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    assert ca["flops"] < r.flops
+    assert c.cost_analysis()["flops"] < r.flops
 
 
 def test_nested_scan():
