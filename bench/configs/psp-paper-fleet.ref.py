@@ -25,6 +25,15 @@ the protocol: the tick's phases, the barrier predicates, the sample (the
 β smallest peer scores, self excluded), the adaptive updates and the
 SGD step, in float32 with every contraction at ``Precision.HIGHEST``.
 
+The noise layout is part of the semantics this benchmark holds the sweep
+to, as the results themselves are: the keys folded per supertick and per
+tick, the stride rule (:func:`_stride`: :data:`NOISE_BUDGET` and the
+engine's :data:`ROW_BLOCK` row padding) and the shapes drawn.  A change to
+the program that draws any number elsewhere reads not correct, even where
+it means the same protocol.  Row padding alone moves nothing at the
+paper's size: one tick's noise fills the budget, so the stride is 1 for
+any padding (``tests/test_sweep.py`` pins it).
+
 ``precision="high"`` is the control: the data plane's contractions at
 ``Precision.HIGH``, which a TPU computes in three bfloat16 passes (a CPU
 computes it in float32, so there it changes nothing).

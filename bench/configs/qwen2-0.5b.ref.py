@@ -51,11 +51,27 @@ FP8_MAX = 448.0
 # --------------------------------------------------------------------------- #
 # parameters
 # --------------------------------------------------------------------------- #
+#: the architecture this reference implements, as the configuration file
+#: states it; any other is refused, not approximated
+ARCHITECTURE = {"family": "dense", "layer_pattern": ["attn"],
+                "mlp_type": "swiglu", "hidden_act": "silu", "qkv_bias": True,
+                "tie_word_embeddings": True}
+
+
+def check_architecture(cfg: dict) -> None:
+    """Raises unless ``cfg`` states the architecture written here."""
+    wrong = {k: cfg.get(k) for k, v in ARCHITECTURE.items()
+             if cfg.get(k) != v}
+    if wrong:
+        raise ValueError(f"the qwen2 reference does not implement {wrong}")
+
+
 def param_layout(cfg: dict) -> list[tuple[str, tuple, str]]:
     """(path, shape, init) of every parameter, in sorted-path order."""
+    check_architecture(cfg)
     d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
     h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd, ff, v = d // h, cfg["intermediate_size"], cfg["vocab_size"]
+    hd, ff, v = cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"]
     return [
         ("embed", (v, d), "normal"),
         ("final_norm", (d,), "ones"),
@@ -156,17 +172,30 @@ def ce_sum(params: dict, tokens: jax.Array, cfg: dict,
 
 
 @functools.lru_cache(maxsize=None)
-def _grad_fn(cfg_items: tuple, quant: str | None):
+def _grad_fn(cfg_items: tuple, quant: str | None, grad: bool = True):
     cfg = dict(cfg_items)
-    return jax.jit(jax.value_and_grad(
-        lambda p, t: ce_sum(p, t, cfg, quant)))
+    f = functools.partial(ce_sum, cfg=cfg, quant=quant)
+    return jax.jit(jax.value_and_grad(f) if grad else f)
+
+
+def _items(cfg: dict) -> tuple:
+    return tuple(sorted((k, v) for k, v in cfg.items()
+                        if not isinstance(v, (list, dict))))
+
+
+def loss_only(params: dict, tokens: np.ndarray, cfg: dict, block: int,
+              quant: str | None = None) -> float:
+    """Mean cross-entropy of ``tokens``, in row blocks, no gradient."""
+    f = _grad_fn(_items(cfg), quant, grad=False)
+    n = tokens.shape[0] * (tokens.shape[1] - 1)
+    return sum(float(f(params, jnp.asarray(tokens[i:i + block])))
+               for i in range(0, tokens.shape[0], block)) / n
 
 
 def loss_and_grad(params: dict, tokens: np.ndarray, cfg: dict, block: int,
                   quant: str | None = None):
     """Mean cross-entropy of ``tokens`` and its gradient, in row blocks."""
-    f = _grad_fn(tuple(sorted((k, v) for k, v in cfg.items()
-                              if not isinstance(v, (list, dict)))), quant)
+    f = _grad_fn(_items(cfg), quant)
     n = tokens.shape[0] * (tokens.shape[1] - 1)
     total, grad = 0.0, None
     for i in range(0, tokens.shape[0], block):
@@ -197,8 +226,18 @@ def clip(grad: dict, max_norm: float = 1.0) -> dict:
     return {k: g * scale for k, g in grad.items()}
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-def _adam(params, mu, nu, g, eta, t, b1, b2, eps):
+@functools.partial(jax.jit, donate_argnums=(0,))
+def clip_mean(grads: list[dict]) -> dict:
+    """Each worker's gradient clipped to global norm 1, then their mean;
+    the gradients' buffers are given up to it."""
+    total = None
+    for g in grads:
+        g = clip(g)
+        total = g if total is None else {k: total[k] + g[k] for k in g}
+    return {k: v / len(grads) for k, v in total.items()}
+
+
+def _adam_math(params, mu, nu, g, eta, t, b1, b2, eps):
     mu = {k: b1 * mu[k] + (1 - b1) * g[k] for k in g}
     nu = {k: b2 * nu[k] + (1 - b2) * g[k] * g[k] for k in g}
     c1, c2 = 1 - b1 ** t, 1 - b2 ** t
@@ -206,6 +245,11 @@ def _adam(params, mu, nu, g, eta, t, b1, b2, eps):
                                     / (jnp.sqrt(nu[k] / c2) + eps))
               for k in g}
     return params, mu, nu
+
+
+_adam = jax.jit(_adam_math, donate_argnums=(0, 1, 2))
+#: for parameters that a worker's view still holds
+_adam_keep = jax.jit(_adam_math, donate_argnums=(1, 2))
 
 
 class Adam:
@@ -216,10 +260,12 @@ class Adam:
         self.mu = {k: jnp.zeros_like(v) for k, v in params.items()}
         self.nu = {k: jnp.zeros_like(v) for k, v in params.items()}
 
-    def step(self, params: dict, g: dict) -> dict:
+    def step(self, params: dict, g: dict, keep: bool = False) -> dict:
+        """The next parameters; ``keep`` leaves ``params`` intact, else
+        its buffers are given up to the update."""
         eta = lr_at(self.wl, self.t)
         self.t += 1
-        params, self.mu, self.nu = _adam(
+        params, self.mu, self.nu = (_adam_keep if keep else _adam)(
             params, self.mu, self.nu, g, jnp.float32(eta),
             jnp.float32(self.t), self.wl["adam_b1"], self.wl["adam_b2"],
             self.wl["adam_eps"])
@@ -264,6 +310,7 @@ class Clock:
             jax.random.PRNGKey(wl["psp_seed"]), 3)
         n_slow = int(round(wl["straggler_frac"] * W))
         slow = jax.random.permutation(k_slow, jnp.arange(W) < n_slow)
+        self.slow = np.asarray(slow)
         self.base = wl["base_compute"] * jnp.where(
             slow, jnp.float32(wl["straggler_slowdown"]), jnp.float32(1.0))
         self.busy = self._dur(k_dur)
@@ -321,7 +368,8 @@ def replay(cfg: dict, wl: dict, seed: int, quant: str | None = None,
     ``fault`` plants a fault, for reading what the comparison makes of it:
     ``half_batch`` leaves out the second half of each worker's rows and
     takes the mean over the rest; ``no_barrier`` lets every completed
-    worker start its next step.
+    worker start its next step; ``straggler_push`` loses every push of a
+    straggler (its gradient never reaches the server).
     """
     key = jax.random.PRNGKey(traffic.seed32(seed))
     params = init_params(cfg, key)
@@ -333,7 +381,7 @@ def replay(cfg: dict, wl: dict, seed: int, quant: str | None = None,
     W = wl["workers"] if psp else 1
     shape = ((W, wl["batch"], wl["seq"]) if psp else (wl["batch"], wl["seq"]))
     clock = Clock(wl, open_barrier=fault == "no_barrier") if psp else None
-    views = [params] * W
+    views = [params] * W if psp else []
     for tick in range(wl["check_ticks"]):
         toks = traffic.tokens(seed, tick, shape, cfg["vocab_size"],
                               wl["tokens"])
@@ -341,7 +389,7 @@ def replay(cfg: dict, wl: dict, seed: int, quant: str | None = None,
             toks = toks[..., :max(1, toks.shape[-2] // 2), :]
         if not psp:
             loss, g = loss_and_grad(params, toks, cfg, block, quant)
-            g = clip(g)
+            g = clip_mean([g])
             if out["grad_sq"] is None:
                 out["grad_sq"] = leaf_sq(g)
                 out["grad_sample"] = leaf_sample(g)
@@ -350,24 +398,26 @@ def replay(cfg: dict, wl: dict, seed: int, quant: str | None = None,
             out["pushes"].append(1)
             continue
         push = clock.pushes()
+        if fault == "straggler_push":
+            push &= ~clock.slow
         losses, grads = [], []
         for w in range(W):
             if push[w]:
                 loss, g = loss_and_grad(views[w], toks[w], cfg, block, quant)
-                grads.append(clip(g))
-            elif not push.any():
-                loss, g = loss_and_grad(views[w], toks[w], cfg, block, quant)
-                del g
+                grads.append(g)
+            elif not push.any():    # a tick with no push reports every loss
+                loss = loss_only(views[w], toks[w], cfg, block, quant)
             else:
                 loss = None
             losses.append(loss)
         if push.any():
-            g = jax.tree.map(lambda *x: sum(x) / len(x), *grads)
+            g = clip_mean(grads)
             del grads
             if out["grad_sq"] is None:
                 out["grad_sq"] = leaf_sq(g)
                 out["grad_sample"] = leaf_sample(g)
-            params = opt.step(params, g)
+            params = opt.step(params, g,
+                              keep=any(v is params for v in views))
             del g
             out["loss"].append(float(np.mean([losses[w] for w in range(W)
                                               if push[w]])))

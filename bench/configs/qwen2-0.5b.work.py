@@ -10,8 +10,8 @@ from __future__ import annotations
 
 
 def shapes(cfg: dict) -> dict:
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return {"d": d, "h": h, "kv": cfg["num_key_value_heads"], "hd": d // h,
+    return {"d": cfg["hidden_size"], "h": cfg["num_attention_heads"],
+            "kv": cfg["num_key_value_heads"], "hd": cfg["head_dim"],
             "ff": cfg["intermediate_size"], "v": cfg["vocab_size"],
             "L": cfg["num_hidden_layers"]}
 

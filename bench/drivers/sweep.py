@@ -2,11 +2,13 @@
 
 One sweep runs every row of the cell (a policy at a straggler fraction)
 on the configuration's fleet, with the tick implementation the workload
-names (``PSP_TICK_IMPL``) on a one-chip ``1x1`` mesh.  Sweep ``k`` of a
-run draws its rows' seeds from ``(--seed, k)``, so every sweep is fresh
-and every run repeats.  Set-up runs sweep 0, which compiles or loads
-every chunk program the sweep's plan uses; the window then runs sweeps
-1, 2, ... until ``--seconds`` have passed, each ended by ``run_sweep``
+names (``PSP_TICK_IMPL``) on a one-chip ``1x1`` mesh.  The rows' seeds
+come from the workload's fixed pool of sweeps (``pool``), taken in an
+order drawn from ``--seed`` (:func:`row_seeds`): every run draws from
+the same sweeps, so the seed moves their order and not the work, and
+every run repeats.  Set-up runs sweep 0, which compiles or loads every
+chunk program the sweep's plan uses; the window then runs sweeps 1, 2,
+... until ``--seconds`` have passed, each ended by ``run_sweep``
 returning its results on the host.
 """
 from __future__ import annotations
@@ -47,6 +49,19 @@ def sim_configs(cfg: dict, wl: dict, seeds: list[int]) -> list:
         for (name, frac), seed in zip(ref.rows(wl), seeds)]
 
 
+def row_seeds(wl: dict, seed: int, k: int) -> list[int]:
+    """Row seeds of sweep ``k`` of a run with ``seed``: entry
+    ``order[k mod n]`` of the workload's pool of ``n`` sweeps, where
+    ``order`` is a permutation of the pool drawn from ``seed``."""
+    pool = wl["pool"]
+    n = int(pool["sweeps"])
+    order = np.random.default_rng(
+        np.random.SeedSequence([int(seed), 3])).permutation(n)
+    j = int(order[k % n])
+    return [traffic.seed32(pool["seed"], j, r)
+            for r in range(len(_reference(wl).rows(wl)))]
+
+
 class Session:
     """The cell's sweeps: the warm-up, the window's, the traced ones."""
 
@@ -61,7 +76,7 @@ class Session:
 
     def seeds(self, k: int) -> list[int]:
         """Row seeds of sweep ``k``."""
-        return [traffic.seed32(self.seed, k, r) for r in range(self.n_rows)]
+        return row_seeds(self.wl, self.seed, k)
 
     def _sweep(self) -> dict:
         from repro.core.vector_sim import run_sweep
@@ -76,15 +91,16 @@ class Session:
         return out
 
     def _sweeps(self, seconds: float) -> dict:
-        done = []
-        t_first = time.perf_counter()
+        done, call_s = [], []
+        t_first = t1 = time.perf_counter()
         while True:
             with jax.profiler.TraceAnnotation("bench.sweep"):
                 done.append(self._sweep())
-            t1 = time.perf_counter()
+            t0, t1 = t1, time.perf_counter()
+            call_s.append(t1 - t0)
             if t1 - t_first >= seconds:
                 break
-        return {"sweeps": done, "elapsed": t1 - t_first,
+        return {"sweeps": done, "elapsed": t1 - t_first, "call_s": call_s,
                 "node_steps": int(sum(d["steps"].sum() for d in done)),
                 "updates": int(sum(d["updates"].sum() for d in done))}
 

@@ -40,17 +40,41 @@ import files  # noqa: E402
 import traffic  # noqa: E402
 
 
+#: the activation each of the program's MLP types applies, as published
+#: configs name it (its gelu is the tanh approximation)
+MLP_ACT = {"swiglu": ("silu",), "geglu": ("gelu_pytorch_tanh", "gelu_new"),
+           "gelu": ("gelu_pytorch_tanh", "gelu_new")}
+#: what the configuration file states of the architecture beyond its
+#: sizes; each is required, none has a default
+ARCH_KEYS = ("family", "layer_pattern", "mlp_type", "qkv_bias", "head_dim",
+             "hidden_act", "tie_word_embeddings")
+
+
 def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file's sizes."""
+    """The program's ``ModelConfig`` for a configuration file.
+
+    Sizes and architecture both come from the file: a configuration that
+    leaves out one of :data:`ARCH_KEYS`, or names an MLP type or an
+    activation that do not go together, is refused.
+    """
     from repro.configs.base import ModelConfig
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
+    missing = [k for k in ARCH_KEYS if k not in cfg]
+    if missing:
+        raise ValueError(f"configuration {cfg['name']!r} does not state "
+                         f"{', '.join(missing)}")
+    mlp, act = cfg["mlp_type"], cfg["hidden_act"]
+    if act not in MLP_ACT.get(mlp, ()):
+        raise ValueError(f"configuration {cfg['name']!r}: no known MLP "
+                         f"{mlp!r} with activation {act!r}")
     return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=d, n_heads=h,
+        name=cfg["name"], family=cfg["family"],
+        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        n_heads=cfg["num_attention_heads"],
         n_kv_heads=cfg["num_key_value_heads"],
         d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=d // h, qkv_bias=True, layer_pattern=("attn",),
-        mlp_type="swiglu", rope_theta=cfg["rope_theta"],
+        head_dim=cfg["head_dim"], qkv_bias=cfg["qkv_bias"],
+        layer_pattern=tuple(cfg["layer_pattern"]), mlp_type=mlp,
+        rope_theta=cfg["rope_theta"],
         tie_embeddings=cfg["tie_word_embeddings"],
         norm_eps=cfg["rms_norm_eps"], dtype=cfg["compute_dtype"],
         param_dtype=cfg["param_dtype"])
@@ -169,30 +193,34 @@ class Session:
 
     # ------------------------------------------------------------------ #
     def _ticks(self, seconds: float) -> dict:
-        tick_s, pushes = [], 0
+        tick_s, parts, pushes = [], [], 0
         t_first = time.perf_counter()
         while True:
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench.data"):
                 toks = self._feed()
+            ta = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench.step"):
                 out = self._call(toks)
+            tb = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench.read"):
                 _, p = self._read(out)
             self.tick += 1
             t1 = time.perf_counter()
             tick_s.append(t1 - t0)
+            parts.append({"data": ta - t0, "step": tb - ta, "read": t1 - tb})
             pushes += p
             if t1 - t_first >= seconds:
                 break
         return {"ticks": len(tick_s), "pushes": pushes,
-                "elapsed": t1 - t_first, "tick_s": tick_s}
+                "elapsed": t1 - t_first, "call_s": tick_s,
+                "call_parts": parts}
 
     def window(self, seconds: float) -> dict:
         """Ticks for ``seconds``; the end-to-end metrics and counters."""
         w = self._ticks(seconds)
         landed = w["pushes"] * self.tokens_per_push
-        p95 = float(np.percentile(np.asarray(w["tick_s"]) * 1e3, 95))
+        p95 = float(np.percentile(np.asarray(w["call_s"]) * 1e3, 95))
         w.update(
             landed_tokens=landed, workers=self.W,
             attempted=w["ticks"], failed=0,

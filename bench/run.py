@@ -39,6 +39,8 @@ import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
+import numpy as np  # noqa: E402
+
 from files import BENCH, load_json, module  # noqa: E402
 
 ROOT = os.path.dirname(BENCH)
@@ -100,12 +102,50 @@ class CompileCounter:
             self.count += 1
 
 
+class GcTimer:
+    """Collections of Python's garbage collector, and their time, while
+    ``active``: a pause that the window's clock would otherwise hide."""
+
+    def __init__(self):
+        self.active = False
+        self.count = self.oldest = 0
+        self.seconds = 0.0
+        self._t0 = None
+        gc.callbacks.append(self._on)
+
+    def _on(self, phase: str, info: dict) -> None:
+        if not self.active:
+            return
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.seconds += time.perf_counter() - self._t0
+            self.count += 1
+            self.oldest += info["generation"] == 2
+            self._t0 = None
+
+
 def peak_bytes(devices) -> int | None:
     """``peak_bytes_in_use`` of the fullest chip, where the backend says."""
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
              for d in devices]
     peaks = [p for p in peaks if p is not None]
     return max(peaks) if peaks else None
+
+
+def window_line(win: dict, compiles: CompileCounter, pauses: GcTimer) -> str:
+    """What the window held besides the cell's work, for standard error:
+    its longest call (by phase, where the driver times phases), the
+    compiles and the garbage collector's pauses inside it."""
+    i = int(np.argmax(win["call_s"]))
+    parts = win.get("call_parts")
+    phases = "".join(f", {k} {v!r} s" for k, v in parts[i].items()) \
+        if parts else ""
+    return (f"window: {len(win['call_s'])} calls in {win['elapsed']!r} s, "
+            f"longest {win['call_s'][i]!r} s (call {i}{phases}), "
+            f"{compiles.count} compiles, {pauses.count} gc collections "
+            f"({pauses.oldest} of the oldest generation) taking "
+            f"{pauses.seconds!r} s")
 
 
 def per_layer(bench: dict, name: str, ctx: dict) -> dict:
@@ -134,14 +174,16 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     import jax
     wl, cfg = files or cell(name)
     drv = driver or module("drivers", f"{wl['driver']}.py")
-    compiles = CompileCounter()
+    compiles, pauses = CompileCounter(), GcTimer()
     sess = drv.setup(wl, cfg, seed)
     setup_s = time.perf_counter() - T_START
-    compiles.active = True
+    compiles.active = pauses.active = True
     win = sess.window(seconds)
+    print(window_line(win, compiles, pauses), file=sys.stderr)
     trace_dir = os.path.join(ROOT, ".bench_trace", name)
     tr = sess.traced(trace_dir, wl["trace_seconds"]) if trace else None
-    compiles.active = False
+    compiles.active = pauses.active = False
+    gc.callbacks.remove(pauses._on)
     used = jax.devices()[:wl["chips"]]
     peak = peak_bytes(used)
     checks = sess.check()

@@ -33,6 +33,9 @@ def _broken_psp(fault):
         def step(s, b):
             if fault == "half_batch":
                 b = b[:, :b.shape[1] // 2]
+            elif fault == "straggler_push":   # its completion never pushes
+                s = s._replace(pushed=s.pushed | (s.slow & (
+                    s.busy_until <= s.now)))
             new, m = psp_train_step(pcfg, grad_fn, opt.update, s, b)
             if fault == "unchanged":
                 new = s
@@ -86,7 +89,7 @@ def test_sound_run_is_correct(name):
 
 @pytest.mark.parametrize("name,fault", [
     (PBSP, "unchanged"), (PBSP, "half_batch"), (PBSP, "barrier"),
-    (SYNC, "unchanged"), (SYNC, "half_batch")])
+    (PBSP, "straggler_push"), (SYNC, "unchanged"), (SYNC, "half_batch")])
 def test_fault_is_not_correct(monkeypatch, name, fault):
     r = _run(name, monkeypatch, fault)
     assert not r["correct"], r["checks"]

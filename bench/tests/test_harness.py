@@ -99,3 +99,25 @@ def test_benchmark_names_its_files():
                                            f"{m['name']}.py"))
         for name in m["workloads"]:
             assert name in e2e[m["moves"]].get("workloads", [name])
+
+
+@pytest.mark.parametrize("change", [{"qkv_bias": None},
+                                    {"head_dim": None},
+                                    {"hidden_act": "gelu"},
+                                    {"mlp_type": "moe_swiglu"}])
+def test_trainer_refuses_an_architecture_the_file_does_not_state(change):
+    drv = run.module("drivers", "train.py")
+    _, cfg = run.cell("train.qwen2-0.5b.pbsp")
+    cfg = {k: v for k, v in dict(cfg, **change).items() if v is not None}
+    with pytest.raises(ValueError):
+        drv.model_config(cfg)
+
+
+def test_trainer_builds_the_architecture_the_file_states():
+    drv = run.module("drivers", "train.py")
+    _, cfg = run.cell("train.qwen2-0.5b.pbsp")
+    mc = drv.model_config(cfg)
+    assert (mc.head_dim, mc.qkv_bias, mc.mlp_type, mc.layer_pattern) == \
+        (64, True, "swiglu", ("attn",))
+    mc = drv.model_config(dict(cfg, qkv_bias=False, head_dim=128))
+    assert (mc.head_dim, mc.qkv_bias) == (128, False)

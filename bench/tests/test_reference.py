@@ -84,3 +84,46 @@ def test_reference_refuses_a_sample_it_cannot_draw():
     wl, cfg = tiny.cell(PBSP)
     with pytest.raises(ValueError):
         ref.Clock(dict(wl, workers=4, sample_size=1))
+
+
+def test_checked_ticks_reach_the_stragglers_push_and_the_release():
+    """The pBSP cell's checked ticks cover a whole barrier cycle: the
+    straggler's first push, the blocked fast worker's release, and the
+    fast worker's next push from the view it then pulled."""
+    wl, _ = run.cell(PBSP)
+    clock = ref.Clock(wl)
+    slow = int(np.flatnonzero(clock.slow)[0])
+    fast = 1 - slow
+    events = []
+    for tick in range(wl["check_ticks"]):
+        push = clock.pushes()
+        allowed = clock.advance(push)
+        events += [(tick, "push", w) for w in np.flatnonzero(push)]
+        events += [(tick, "start", w) for w in np.flatnonzero(allowed)]
+    straggler = min(t for t, e, w in events if e == "push" and w == slow)
+    release = min(t for t, e, w in events
+                  if e == "start" and w == fast and t >= straggler)
+    assert any(e == "push" and w == fast and t > release
+               for t, e, w in events)
+    # the fast worker polls while it is blocked
+    assert release - straggler >= 1
+    assert straggler > 3
+
+
+def test_loss_only_is_the_loss_of_loss_and_grad():
+    _, cfg = tiny.cell(PBSP)
+    params = ref.init_params(cfg, jax.random.PRNGKey(5))
+    toks = traffic.tokens(5, 0, (2, 32), 512, {"kind": "successor",
+                                              "follow_p": 0.5})
+    want, _ = ref.loss_and_grad(params, toks, cfg, block=1)
+    assert ref.loss_only(params, toks, cfg, block=1) == pytest.approx(
+        want, rel=1e-6)
+
+
+@pytest.mark.parametrize("change", [{"qkv_bias": False},
+                                    {"mlp_type": "geglu"},
+                                    {"tie_word_embeddings": False}])
+def test_reference_refuses_an_architecture_it_does_not_implement(change):
+    _, cfg = tiny.cell(PBSP)
+    with pytest.raises(ValueError):
+        ref.param_layout(dict(cfg, **change))

@@ -89,6 +89,23 @@ def test_fault_is_not_correct(monkeypatch, fault):
     assert not r["correct"], r["checks"]
 
 
+@pytest.mark.parametrize("name", [NINE, CLASSIC])
+def test_every_seed_draws_the_same_sweeps(name):
+    """The seed orders the workload's pool of sweeps and draws no others,
+    so a window's node-steps do not move with the seed."""
+    wl, _ = run.cell(name)
+    n = wl["pool"]["sweeps"]
+
+    def pool(seed):
+        return [tuple(drv.row_seeds(wl, seed, k)) for k in range(n)]
+
+    a, b = pool(3), pool(2 ** 33 + 5)
+    assert len(set(a)) == n and sorted(a) == sorted(b) and a != b
+    assert pool(3) == a
+    assert drv.row_seeds(wl, 3, n) == list(a[0])
+    assert all(len(s) == len(ref.rows(wl)) for s in a)
+
+
 def test_work_count_by_hand():
     _, cfg = _cell(NINE, P=64)
     # m = 8, d = 16: a push reads 8 x 17 + 16 floats, a pull writes 16;
@@ -113,3 +130,17 @@ def test_stride_matches_the_planner():
                           P, batch=cfg["batch"], d=d, k_max=b["k_max"],
                           masked=False, has_churn=False, mesh=(1, 1))
         assert b["stride"] == plan.stride, (name, P, d)
+
+
+@pytest.mark.parametrize("name", [NINE, CLASSIC])
+def test_paper_stride_does_not_depend_on_row_padding(monkeypatch, name):
+    """At the paper's size one tick's noise fills the budget, so the
+    stride is 1 however many rows the engine pads to: a data plane that
+    runs the 9 real rows alone draws every number where it was."""
+    wl, cfg = run.cell(name)
+    seeds = list(range(len(ref.rows(wl))))
+    strides = set()
+    for block in (1, 9, 16, 32):
+        monkeypatch.setattr(ref, "ROW_BLOCK", block)
+        strides.add(ref.batch(cfg, wl, seeds)["stride"])
+    assert strides == {1}

@@ -15,7 +15,7 @@ import run
 def cell(name: str, dtype: str = "float32") -> tuple[dict, dict]:
     wl, cfg = run.cell(name)
     cfg = dict(cfg, hidden_size=64, intermediate_size=128,
-               num_hidden_layers=2, num_attention_heads=4,
+               num_hidden_layers=2, num_attention_heads=4, head_dim=16,
                num_key_value_heads=2, vocab_size=512, compute_dtype=dtype)
     wl = dict(wl, seq=32)
     return wl, cfg
