@@ -473,135 +473,146 @@ def psp_apply_tick(
     push mask discards those columns identically either way, which is
     what makes the cluster bit-exact against the in-process trainer).
 
+    Each phase runs under a device scope (``jax.named_scope``) that a
+    profiler trace reads back: ``train.barrier`` (churn, the barrier
+    decision, the clock advance, the metrics), ``train.optimizer`` (the
+    masked server update) and ``train.pull`` (the views' pull).  The
+    gradients are ``compute``'s; the trainers scope them ``train.grads``.
+
     Returns: (new_state, metrics)
     """
-    if cfg.has_churn:
-        # (0) elastic churn phase: fire due pre-sampled leave/join events.
-        # The extra key split is compiled in only when churn is enabled,
-        # so the churn=None RNG stream is identical to the fixed-worker
-        # trainer (bit-for-bit on golden/regression tests).
-        key, k_bar, k_dur, k_churn = jax.random.split(state.key, 4)
-        state = _fire_churn(cfg, state, k_churn)
-    else:
-        key, k_bar, k_dur = jax.random.split(state.key, 3)
-    alive = state.alive
+    with jax.named_scope("train.barrier"):
+        if cfg.has_churn:
+            # (0) elastic churn phase: fire due pre-sampled leave/join events.
+            # The extra key split is compiled in only when churn is enabled,
+            # so the churn=None RNG stream is identical to the fixed-worker
+            # trainer (bit-for-bit on golden/regression tests).
+            key, k_bar, k_dur, k_churn = jax.random.split(state.key, 4)
+            state = _fire_churn(cfg, state, k_churn)
+        else:
+            key, k_bar, k_dur = jax.random.split(state.key, 3)
+        alive = state.alive
 
     # (1) every worker computes on its own (possibly stale) view
     losses, grads = compute(state)
 
-    # (2) completions push to the server; departed workers are masked out
-    # of the psum — zero gradient, zero bytes
-    completed = state.busy_until <= state.now
-    push_mask = completed & ~state.pushed & alive
-    denom = jnp.maximum(jnp.sum(push_mask), 1)
-    if cfg.contribution == "mean-alive":
-        # churn-aware scaling: divide by the carried alive-count EMA, not
-        # by this tick's pushing-set size — per-worker contribution stays
-        # stable as churn shrinks/grows the population.  Reads the OLD
-        # state (the EMA update lands below with the policy state).
-        scale = 1.0 / jnp.maximum(state.policy["denom"], 1.0)
-    else:
-        scale = jnp.where(cfg.contribution == "mean", 1.0 / denom, 1.0)
+    with jax.named_scope("train.optimizer"):
+        # (2) completions push to the server; departed workers are masked out
+        # of the psum — zero gradient, zero bytes
+        completed = state.busy_until <= state.now
+        push_mask = completed & ~state.pushed & alive
+        denom = jnp.maximum(jnp.sum(push_mask), 1)
+        if cfg.contribution == "mean-alive":
+            # churn-aware scaling: divide by the carried alive-count EMA, not
+            # by this tick's pushing-set size — per-worker contribution stays
+            # stable as churn shrinks/grows the population.  Reads the OLD
+            # state (the EMA update lands below with the policy state).
+            scale = 1.0 / jnp.maximum(state.policy["denom"], 1.0)
+        else:
+            scale = jnp.where(cfg.contribution == "mean", 1.0 / denom, 1.0)
 
-    def _masked_sum(g):
-        m = push_mask.reshape((-1,) + (1,) * (g.ndim - 1))
-        return jnp.sum(jnp.where(m, g, 0), axis=0) * scale
+        def _masked_sum(g):
+            m = push_mask.reshape((-1,) + (1,) * (g.ndim - 1))
+            return jnp.sum(jnp.where(m, g, 0), axis=0) * scale
 
-    server_grad = jax.tree.map(_masked_sum, grads)
-    any_push = jnp.any(push_mask)
-    updates, new_opt = opt_update(server_grad, state.opt_state,
-                                  state.server_params)
-    new_params = jax.tree.map(
-        lambda p, u: jnp.where(any_push, p + u, p),
-        state.server_params, updates)
-    new_opt = jax.tree.map(
-        lambda new, old: jnp.where(any_push, new, old), new_opt,
-        state.opt_state)
-    pushed = state.pushed | push_mask
+        server_grad = jax.tree.map(_masked_sum, grads)
+        any_push = jnp.any(push_mask)
+        updates, new_opt = opt_update(server_grad, state.opt_state,
+                                      state.server_params)
+        new_params = jax.tree.map(
+            lambda p, u: jnp.where(any_push, p + u, p),
+            state.server_params, updates)
+        new_opt = jax.tree.map(
+            lambda new, old: jnp.where(any_push, new, old), new_opt,
+            state.opt_state)
+        pushed = state.pushed | push_mask
 
-    # (3) barrier: completed alive workers try to start their next step.
-    # The next-step duration is drawn *before* the decide so adaptive
-    # policies (Elastic-BSP's duration EMA) can observe it; k_dur and
-    # k_bar are independent splits of the same parent key, so hoisting
-    # the draw leaves every RNG stream bit-identical.  For static
-    # barriers ``decide`` is exactly the old ``_barrier_allowed``
-    # predicate and passes the (empty) policy state through.
-    next_dur = _duration(cfg, k_dur, state.slow)
-    allowed, new_policy = cfg.barrier_policy.decide(
-        state.policy, k_bar, state.step, next_dur,
-        alive if cfg.has_churn else None)
-    allowed = allowed & completed & alive
-    new_step = state.step + allowed.astype(jnp.int32)
-    new_busy = jnp.where(allowed, state.now + next_dur, state.busy_until)
-    new_pushed = jnp.where(allowed, False, pushed)
+    with jax.named_scope("train.barrier"):
+        # (3) barrier: completed alive workers try to start their next step.
+        # The next-step duration is drawn *before* the decide so adaptive
+        # policies (Elastic-BSP's duration EMA) can observe it; k_dur and
+        # k_bar are independent splits of the same parent key, so hoisting
+        # the draw leaves every RNG stream bit-identical.  For static
+        # barriers ``decide`` is exactly the old ``_barrier_allowed``
+        # predicate and passes the (empty) policy state through.
+        next_dur = _duration(cfg, k_dur, state.slow)
+        allowed, new_policy = cfg.barrier_policy.decide(
+            state.policy, k_bar, state.step, next_dur,
+            alive if cfg.has_churn else None)
+        allowed = allowed & completed & alive
+        new_step = state.step + allowed.astype(jnp.int32)
+        new_busy = jnp.where(allowed, state.now + next_dur, state.busy_until)
+        new_pushed = jnp.where(allowed, False, pushed)
 
-    def _pull(view, p):
-        m = allowed.reshape((-1,) + (1,) * p.ndim)
-        return jnp.where(m, p[None], view)
+    with jax.named_scope("train.pull"):
+        def _pull(view, p):
+            m = allowed.reshape((-1,) + (1,) * p.ndim)
+            return jnp.where(m, p[None], view)
 
-    new_views = jax.tree.map(_pull, state.views, new_params)
+        new_views = jax.tree.map(_pull, state.views, new_params)
 
-    if cfg.contribution == "mean-alive":
-        new_policy = dict(new_policy)
-        new_policy["denom"] = (0.9 * state.policy["denom"]
-                               + 0.1 * jnp.sum(alive).astype(jnp.float32))
+    with jax.named_scope("train.barrier"):
+        if cfg.contribution == "mean-alive":
+            new_policy = dict(new_policy)
+            new_policy["denom"] = (0.9 * state.policy["denom"]
+                                   + 0.1 * jnp.sum(alive).astype(jnp.float32))
 
-    # (4) event-driven virtual-time advance: jump to the earlier of (a) the
-    # next completion of a still-busy alive worker, (b) the next poll of a
-    # barrier-blocked worker (the paper's "holds until condition is
-    # satisfied" — re-sampling costs a poll interval of virtual time).
-    # Departed workers' frozen clocks never hold time back; with at least
-    # two alive workers every tick either has someone busy or someone
-    # polling, so the clock always advances and pending joins fire.
-    blocked = completed & ~allowed & alive
-    next_busy = jnp.min(jnp.where((new_busy > state.now) & alive, new_busy,
-                                  jnp.inf))
-    next_poll = jnp.where(jnp.any(blocked),
-                          state.now + cfg.poll_interval, jnp.inf)
-    next_time = jnp.minimum(next_busy, next_poll)
-    new_now = jnp.where(jnp.isfinite(next_time),
-                        jnp.maximum(state.now, next_time), state.now)
+        # (4) event-driven virtual-time advance: jump to the earlier of (a) the
+        # next completion of a still-busy alive worker, (b) the next poll of a
+        # barrier-blocked worker (the paper's "holds until condition is
+        # satisfied" — re-sampling costs a poll interval of virtual time).
+        # Departed workers' frozen clocks never hold time back; with at least
+        # two alive workers every tick either has someone busy or someone
+        # polling, so the clock always advances and pending joins fire.
+        blocked = completed & ~allowed & alive
+        next_busy = jnp.min(jnp.where((new_busy > state.now) & alive, new_busy,
+                                      jnp.inf))
+        next_poll = jnp.where(jnp.any(blocked),
+                              state.now + cfg.poll_interval, jnp.inf)
+        next_time = jnp.minimum(next_busy, next_poll)
+        new_now = jnp.where(jnp.isfinite(next_time),
+                            jnp.maximum(state.now, next_time), state.now)
 
-    new_state = state._replace(
-        server_params=new_params,
-        opt_state=new_opt,
-        views=new_views,
-        step=new_step,
-        busy_until=new_busy,
-        pushed=new_pushed,
-        now=new_now,
-        key=key,
-        tick=state.tick + 1,
-        total_pushes=state.total_pushes + jnp.sum(push_mask),
-        policy=new_policy,
-    )
-    if cfg.has_churn:
-        # progress statistics over the *current* worker set only — a
-        # departed straggler's frozen counter is not progress
-        n_alive = jnp.maximum(jnp.sum(alive), 1)
-        mean_step = (jnp.sum(jnp.where(alive, new_step, 0))
-                     / n_alive.astype(jnp.float32))
-        alive_steps_max = jnp.max(jnp.where(alive, new_step, _I32_MIN))
-        alive_steps_min = jnp.min(
-            jnp.where(alive, new_step, jnp.iinfo(jnp.int32).max))
-        step_spread = alive_steps_max - alive_steps_min
-    else:
-        mean_step = jnp.mean(new_step.astype(jnp.float32))
-        step_spread = jnp.max(new_step) - jnp.min(new_step)
-    metrics = {
-        # pushed-worker mean; falls back to the all-worker mean on ticks
-        # where nobody completed (avoids misleading 0.0 readouts)
-        "loss": jnp.where(any_push,
-                          jnp.sum(jnp.where(push_mask, losses, 0)) / denom,
-                          jnp.mean(losses)),
-        "pushes": jnp.sum(push_mask),
-        "allowed": jnp.sum(allowed),
-        "blocked": jnp.sum(blocked),
-        "alive": jnp.sum(alive),
-        "mean_step": mean_step,
-        "step_spread": step_spread,
-        "virtual_time": new_now,
-    }
+        new_state = state._replace(
+            server_params=new_params,
+            opt_state=new_opt,
+            views=new_views,
+            step=new_step,
+            busy_until=new_busy,
+            pushed=new_pushed,
+            now=new_now,
+            key=key,
+            tick=state.tick + 1,
+            total_pushes=state.total_pushes + jnp.sum(push_mask),
+            policy=new_policy,
+        )
+        if cfg.has_churn:
+            # progress statistics over the *current* worker set only — a
+            # departed straggler's frozen counter is not progress
+            n_alive = jnp.maximum(jnp.sum(alive), 1)
+            mean_step = (jnp.sum(jnp.where(alive, new_step, 0))
+                         / n_alive.astype(jnp.float32))
+            alive_steps_max = jnp.max(jnp.where(alive, new_step, _I32_MIN))
+            alive_steps_min = jnp.min(
+                jnp.where(alive, new_step, jnp.iinfo(jnp.int32).max))
+            step_spread = alive_steps_max - alive_steps_min
+        else:
+            mean_step = jnp.mean(new_step.astype(jnp.float32))
+            step_spread = jnp.max(new_step) - jnp.min(new_step)
+        metrics = {
+            # pushed-worker mean; falls back to the all-worker mean on ticks
+            # where nobody completed (avoids misleading 0.0 readouts)
+            "loss": jnp.where(any_push,
+                              jnp.sum(jnp.where(push_mask, losses, 0)) / denom,
+                              jnp.mean(losses)),
+            "pushes": jnp.sum(push_mask),
+            "allowed": jnp.sum(allowed),
+            "blocked": jnp.sum(blocked),
+            "alive": jnp.sum(alive),
+            "mean_step": mean_step,
+            "step_spread": step_spread,
+            "virtual_time": new_now,
+        }
     return new_state, metrics
 
 

@@ -98,6 +98,7 @@ likewise agree at the distribution level (different dynamics streams) —
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -670,6 +671,11 @@ def run_sweep(configs: Sequence[SimConfig], *,
         chunked scans over the tick grid with the fused tick of
         :mod:`repro.kernels.psp_tick`, sharded over the device mesh —
         :mod:`repro.core.vector_sim_jax`).
+
+    On the jax backend each group's static-state draw (the
+    :class:`VectorSimulator` constructor) is the profiler span
+    ``sweep.init``; :func:`repro.core.vector_sim_jax.run_batch` spans the
+    rest.
     """
     results: List[Optional[SimResult]] = [None] * len(configs)
     key_fn = _merge_key if backend == "jax" else _group_key
@@ -677,8 +683,18 @@ def run_sweep(configs: Sequence[SimConfig], *,
     for i, cfg in enumerate(configs):
         groups.setdefault(key_fn(cfg), []).append(i)
     for idx in groups.values():
-        batch = VectorSimulator([configs[i] for i in idx], dt=dt,
-                                backend=backend).run()
-        for i, res in zip(idx, batch):
+        with _init_span(backend):
+            sim = VectorSimulator([configs[i] for i in idx], dt=dt,
+                                  backend=backend)
+        for i, res in zip(idx, sim.run()):
             results[i] = res
     return results  # type: ignore[return-value]
+
+
+def _init_span(backend: str):
+    """The profiler span ``sweep.init`` (``jax.profiler.TraceAnnotation``)
+    of a jax-backend sweep; the numpy backend does not import jax."""
+    if backend != "jax":
+        return contextlib.nullcontext()
+    import jax
+    return jax.profiler.TraceAnnotation("sweep.init")

@@ -218,56 +218,59 @@ def _compiled_chunk(P: int, d: int, batch: int, k_max: int, has_churn: bool,
         # (the blob and shared-score draws below), so every mesh shape
         # consumes identical noise — the cross-mesh bit-identity
         # invariant.  On the 1×1 mesh all slices are identity and this
-        # is exactly the single-device draw.
-        row_ids, node_ids = params["row_ids"], params["node_ids"]
-        nid0 = lax.axis_index(SWEEP_NODES_AXIS) * p_loc
-        k_sup = jax.random.fold_in(params["key"], x["sup"])
-        k_mini, k_samp, k_dur, k_churn = jax.random.split(k_sup, 4)
-        fold = jax.vmap(jax.random.fold_in, (None, 0))
-        # minibatch blob keyed per (tick, node): the draw comes out in
-        # scan layout directly (stride leading), so no supertick-sized
-        # transpose sits between the RNG and the tick loop.  node_ids is
-        # nodes-major — each node column's rows-padded id block splits
-        # over the rows axis, so the gather over *rows* reassembles the
-        # column's global ids [nid0, nid0 + p_loc) in order and the blob
-        # stays node-sliced (the 100k-node memory win): no shard ever
-        # materialises the (stride, P, m, d+1) block
-        kt = fold(k_mini, x["sup"] * stride + jnp.arange(stride))
-        blob_loc = jax.vmap(lambda k: jax.vmap(
-            lambda kk: jax.random.normal(kk, (batch, d + 1)))(
-                fold(k, node_ids)))(kt)               # (stride, ids_loc, ...)
-        blob = lax.all_gather(blob_loc, SWEEP_ROWS_AXIS, axis=1,
-                              tiled=True)[:, :p_loc]  # (stride, p_loc, ...)
-        dur = jnp.moveaxis(jax.vmap(
-            lambda k: jax.random.uniform(k, (stride, P)))(
-                fold(k_dur, row_ids)), 1, 0)          # (stride, b_loc, P)
-        xt = {"t": x["t"], "lc": x["leave"], "jc": x["join"],
-              "X": blob[..., :d], "mb": blob[..., d],
-              "dur": lax.dynamic_slice_in_dim(dur, nid0, p_loc, 2)}
-        if k_max > 0:
-            if masked:
-                sc = jnp.moveaxis(jax.vmap(
-                    lambda k: jax.random.uniform(k, (stride, P, P)))(
-                        fold(k_samp, row_ids)), 1, 0)
-                # slice the deciding-node axis; peers keep full width
-                xt["scores"] = lax.dynamic_slice_in_dim(sc, nid0, p_loc, 2)
-            elif k_max == 1:
-                u1 = jax.random.uniform(k_samp, (stride, P))
-                xt["u1"] = lax.dynamic_slice_in_dim(u1, nid0, p_loc, 1)
-            else:
-                sc_loc = jax.vmap(
-                    lambda k: jax.random.uniform(k, (stride, P)))(
-                        fold(k_samp, node_ids))
-                sc = lax.all_gather(sc_loc, SWEEP_ROWS_AXIS, tiled=True)
-                xt["scores"] = jnp.moveaxis(sc, 1, 0)[:, :p_loc]
-        if has_churn:
-            cu = jax.vmap(
-                lambda k: jax.random.uniform(k, (stride, 2, P)))(
-                    fold(k_churn, row_ids))
-            xt["leave"] = lax.dynamic_slice_in_dim(
-                jnp.moveaxis(cu[:, :, 0], 0, 1), nid0, p_loc, 2)
-            xt["join"] = lax.dynamic_slice_in_dim(
-                jnp.moveaxis(cu[:, :, 1], 0, 1), nid0, p_loc, 2)
+        # is exactly the single-device draw.  The draws run under the
+        # device scope ``sweep.noise``.
+        with jax.named_scope("sweep.noise"):
+            row_ids, node_ids = params["row_ids"], params["node_ids"]
+            nid0 = lax.axis_index(SWEEP_NODES_AXIS) * p_loc
+            k_sup = jax.random.fold_in(params["key"], x["sup"])
+            k_mini, k_samp, k_dur, k_churn = jax.random.split(k_sup, 4)
+            fold = jax.vmap(jax.random.fold_in, (None, 0))
+            # minibatch blob keyed per (tick, node): the draw comes out in
+            # scan layout directly (stride leading), so no supertick-sized
+            # transpose sits between the RNG and the tick loop.  node_ids is
+            # nodes-major — each node column's rows-padded id block splits
+            # over the rows axis, so the gather over *rows* reassembles the
+            # column's global ids [nid0, nid0 + p_loc) in order and the blob
+            # stays node-sliced (the 100k-node memory win): no shard ever
+            # materialises the (stride, P, m, d+1) block
+            kt = fold(k_mini, x["sup"] * stride + jnp.arange(stride))
+            blob_loc = jax.vmap(lambda k: jax.vmap(
+                lambda kk: jax.random.normal(kk, (batch, d + 1)))(
+                    fold(k, node_ids)))(kt)           # (stride, ids_loc, ...)
+            # (stride, p_loc, ...)
+            blob = lax.all_gather(blob_loc, SWEEP_ROWS_AXIS, axis=1,
+                                  tiled=True)[:, :p_loc]
+            dur = jnp.moveaxis(jax.vmap(
+                lambda k: jax.random.uniform(k, (stride, P)))(
+                    fold(k_dur, row_ids)), 1, 0)          # (stride, b_loc, P)
+            xt = {"t": x["t"], "lc": x["leave"], "jc": x["join"],
+                  "X": blob[..., :d], "mb": blob[..., d],
+                  "dur": lax.dynamic_slice_in_dim(dur, nid0, p_loc, 2)}
+            if k_max > 0:
+                if masked:
+                    sc = jnp.moveaxis(jax.vmap(
+                        lambda k: jax.random.uniform(k, (stride, P, P)))(
+                            fold(k_samp, row_ids)), 1, 0)
+                    # slice the deciding-node axis; peers keep full width
+                    xt["scores"] = lax.dynamic_slice_in_dim(sc, nid0, p_loc, 2)
+                elif k_max == 1:
+                    u1 = jax.random.uniform(k_samp, (stride, P))
+                    xt["u1"] = lax.dynamic_slice_in_dim(u1, nid0, p_loc, 1)
+                else:
+                    sc_loc = jax.vmap(
+                        lambda k: jax.random.uniform(k, (stride, P)))(
+                            fold(k_samp, node_ids))
+                    sc = lax.all_gather(sc_loc, SWEEP_ROWS_AXIS, tiled=True)
+                    xt["scores"] = jnp.moveaxis(sc, 1, 0)[:, :p_loc]
+            if has_churn:
+                cu = jax.vmap(
+                    lambda k: jax.random.uniform(k, (stride, 2, P)))(
+                        fold(k_churn, row_ids))
+                xt["leave"] = lax.dynamic_slice_in_dim(
+                    jnp.moveaxis(cu[:, :, 0], 0, 1), nid0, p_loc, 2)
+                xt["join"] = lax.dynamic_slice_in_dim(
+                    jnp.moveaxis(cu[:, :, 1], 0, 1), nid0, p_loc, 2)
         carry, _ = lax.scan(functools.partial(tick, params), carry, xt)
         err = (jnp.linalg.norm(carry["w"] - params["w_true"], axis=1)
                / params["w_true_norm"])
@@ -452,20 +455,34 @@ def run_batch(sim) -> List[SimResult]:
     writes the final state back so result assembly is shared with the
     numpy path.  One ``device_get`` per sweep moves the traces and final
     state to the host together.
+
+    Its host phases are profiler spans (``jax.profiler.TraceAnnotation``)
+    on the device trace's clock: ``sweep.stage`` (:func:`_prepare`),
+    ``sweep.dispatch`` (the chunk loop), ``sweep.fetch`` (the wait and the
+    ``device_get``) and ``sweep.results`` (traces, write-back, results).
     """
-    B = sim.B
-    chunk_fn, plan, params, carry, xs_chunks = _prepare(sim)
+    with jax.profiler.TraceAnnotation("sweep.stage"):
+        chunk_fn, plan, params, carry, xs_chunks = _prepare(sim)
     errs_d, upds_d = [], []
     rec = 0
-    for xs in xs_chunks:
-        if rec >= plan.n_rec_live:
-            break            # every row is past its horizon: dead chunk
-        carry, (e, u) = chunk_fn(params, carry, xs)
-        errs_d.append(e)
-        upds_d.append(u)
-        rec += e.shape[0]
-    final, errs_rec, upds_rec = jax.device_get(
-        jax.block_until_ready((carry, errs_d, upds_d)))
+    with jax.profiler.TraceAnnotation("sweep.dispatch"):
+        for xs in xs_chunks:
+            if rec >= plan.n_rec_live:
+                break            # every row is past its horizon: dead chunk
+            carry, (e, u) = chunk_fn(params, carry, xs)
+            errs_d.append(e)
+            upds_d.append(u)
+            rec += e.shape[0]
+    with jax.profiler.TraceAnnotation("sweep.fetch"):
+        final, errs_rec, upds_rec = jax.device_get(
+            jax.block_until_ready((carry, errs_d, upds_d)))
+    with jax.profiler.TraceAnnotation("sweep.results"):
+        return _assemble(sim, plan, final, errs_rec, upds_rec)
+
+
+def _assemble(sim, plan, final, errs_rec, upds_rec) -> List[SimResult]:
+    """The batch's results from the fetched traces and final state."""
+    B = sim.B
     err_t = np.concatenate(errs_rec)[:plan.n_rec_live, :B]
     upd_t = np.concatenate(upds_rec)[:plan.n_rec_live, :B]
 

@@ -150,29 +150,34 @@ def _data_plane(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
     this same XLA code after their control plane, so they agree bit for
     bit whenever their ``fin``/``start`` masks do.
 
+    It runs under the device scope ``sweep.data_plane``, which a profiler
+    trace reads back.
+
     Returns:
       (w', pulled'): updated server models f32[B, d] and node views
       f32[B, P, d].
     """
-    w, pulled = state["w"], state["pulled"]
-    B = w.shape[0]
-    diff = pulled - params["w_true"][:, None, :]
-    W = DATA_PLANE_BLOCK
-    Bp = -(-B // W) * W
+    with jax.named_scope("sweep.data_plane"):
+        w, pulled = state["w"], state["pulled"]
+        B = w.shape[0]
+        diff = pulled - params["w_true"][:, None, :]
+        W = DATA_PLANE_BLOCK
+        Bp = -(-B // W) * W
 
-    def pad(a):
-        return a if Bp == B else jnp.concatenate(
-            [a, jnp.zeros((Bp - B,) + a.shape[1:], a.dtype)], axis=0)
+        def pad(a):
+            return a if Bp == B else jnp.concatenate(
+                [a, jnp.zeros((Bp - B,) + a.shape[1:], a.dtype)], axis=0)
 
-    d_p, f_p, s_p = pad(diff), pad(fin), pad(start)
-    w_p, pu_p = pad(w), pad(pulled)
-    lr_p, ns_p = pad(params["lr"]), pad(params["noise_std"])
-    blocks = [_data_plane_block(rand["X"], d_p[i:i + W], f_p[i:i + W],
-                                s_p[i:i + W], w_p[i:i + W], pu_p[i:i + W],
-                                lr_p[i:i + W], ns_p[i:i + W], rand["mb"])
-              for i in range(0, Bp, W)]
-    return (jnp.concatenate([b[0] for b in blocks])[:B],
-            jnp.concatenate([b[1] for b in blocks])[:B])
+        d_p, f_p, s_p = pad(diff), pad(fin), pad(start)
+        w_p, pu_p = pad(w), pad(pulled)
+        lr_p, ns_p = pad(params["lr"]), pad(params["noise_std"])
+        blocks = [_data_plane_block(rand["X"], d_p[i:i + W], f_p[i:i + W],
+                                    s_p[i:i + W], w_p[i:i + W],
+                                    pu_p[i:i + W], lr_p[i:i + W],
+                                    ns_p[i:i + W], rand["mb"])
+                  for i in range(0, Bp, W)]
+        return (jnp.concatenate([b[0] for b in blocks])[:B],
+                jnp.concatenate([b[1] for b in blocks])[:B])
 
 
 #: carried tick state, in canonical order (control plane, then data plane)
@@ -583,31 +588,33 @@ def psp_tick_sharded(state: Dict[str, jax.Array], rand: Dict[str, jax.Array],
     #    (any node-sliced partial-sum scheme would change the reduction
     #    order and break cross-factorization bit-identity); the server
     #    model is per-row (replicated over the nodes axis), so every
-    #    shard computes the identical w and pulls only its own view slice
-    X = gather(rand["X"], axis=0)               # (P, m, d)
-    mbn = gather(rand["mb"], axis=0)            # (P, m)
-    fin_full = gather(fin)
-    pulled_full = gather(state["pulled"])       # (B, P, d)
-    w = state["w"]
-    diff = pulled_full - params["w_true"][:, None, :]
-    W = DATA_PLANE_BLOCK
-    Bp = -(-B // W) * W
+    #    shard computes the identical w and pulls only its own view slice.
+    #    Device scope ``sweep.data_plane``, as in :func:`_data_plane`.
+    with jax.named_scope("sweep.data_plane"):
+        X = gather(rand["X"], axis=0)               # (P, m, d)
+        mbn = gather(rand["mb"], axis=0)            # (P, m)
+        fin_full = gather(fin)
+        pulled_full = gather(state["pulled"])       # (B, P, d)
+        w = state["w"]
+        diff = pulled_full - params["w_true"][:, None, :]
+        W = DATA_PLANE_BLOCK
+        Bp = -(-B // W) * W
 
-    def pad(a):
-        return a if Bp == B else jnp.concatenate(
-            [a, jnp.zeros((Bp - B,) + a.shape[1:], a.dtype)], axis=0)
+        def pad(a):
+            return a if Bp == B else jnp.concatenate(
+                [a, jnp.zeros((Bp - B,) + a.shape[1:], a.dtype)], axis=0)
 
-    d_p, f_p = pad(diff), pad(fin_full)
-    w_p = pad(w)
-    lr_p, ns_p = pad(params["lr"]), pad(params["noise_std"])
-    zero_pull = jnp.zeros((W,) + pulled_full.shape[1:], pulled_full.dtype)
-    w_blocks = [_data_plane_block(X, d_p[i:i + W], f_p[i:i + W],
-                                  jnp.zeros((W, X.shape[0]), bool),
-                                  w_p[i:i + W], zero_pull,
-                                  lr_p[i:i + W], ns_p[i:i + W], mbn)[0]
-                for i in range(0, Bp, W)]
-    w = jnp.concatenate(w_blocks)[:B]
-    pulled = jnp.where(start[..., None], w[:, None, :], state["pulled"])
+        d_p, f_p = pad(diff), pad(fin_full)
+        w_p = pad(w)
+        lr_p, ns_p = pad(params["lr"]), pad(params["noise_std"])
+        zero_pull = jnp.zeros((W,) + pulled_full.shape[1:], pulled_full.dtype)
+        w_blocks = [_data_plane_block(X, d_p[i:i + W], f_p[i:i + W],
+                                      jnp.zeros((W, X.shape[0]), bool),
+                                      w_p[i:i + W], zero_pull,
+                                      lr_p[i:i + W], ns_p[i:i + W], mbn)[0]
+                    for i in range(0, Bp, W)]
+        w = jnp.concatenate(w_blocks)[:B]
+        pulled = jnp.where(start[..., None], w[:, None, :], state["pulled"])
 
     new_state = {"steps": steps, "alive": alive, "computing": computing,
                  "event_time": event_time, "ready": ready,

@@ -31,14 +31,21 @@ PyTree = Any
 def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
                     rules: Optional[AxisRules] = None,
                     clip_norm: Optional[float] = 1.0) -> Callable:
+    """The plain step ``(params, opt_state, batch) -> (params, opt_state,
+    loss, metrics)``; the device scopes ``train.grads`` (forward, backward,
+    clipping) and ``train.optimizer`` (the update) name its two phases in a
+    profiler trace."""
     def train_step(params, opt_state, batch):
         with use_rules(rules):
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, batch, cfg)
-            if clip_norm is not None:
-                grads = clip_by_norm(grads, clip_norm)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = apply_updates(params, updates)
+            with jax.named_scope("train.grads"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, batch, cfg)
+                if clip_norm is not None:
+                    grads = clip_by_norm(grads, clip_norm)
+            with jax.named_scope("train.optimizer"):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = apply_updates(params, updates)
         return params, opt_state, loss, metrics
     return train_step
 
@@ -69,11 +76,12 @@ def make_psp_train_step(cfg: ModelConfig, psp_cfg: PSPConfig,
                         clip_norm: Optional[float] = 1.0) -> Callable:
     """PSP-barrier training: W worker views, masked server aggregation."""
     def grad_fn(params, microbatch):
-        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, microbatch, cfg)
-        if clip_norm is not None:
-            grads = clip_by_norm(grads, clip_norm)
-        return loss, grads
+        with jax.named_scope("train.grads"):
+            (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                params, microbatch, cfg)
+            if clip_norm is not None:
+                grads = clip_by_norm(grads, clip_norm)
+            return loss, grads
 
     def step(state: PSPState, batch):
         with use_rules(rules):

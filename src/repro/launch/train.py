@@ -86,11 +86,14 @@ def _maybe_resume(a, template):
 def make_psp_step(cfg, pcfg: PSPConfig, opt):
     """The jitted PSP tick ``(state, tokens[W, b, s]) -> (state, metrics)``
     for model ``cfg`` under barrier config ``pcfg``, with the carried
-    ``PSPState`` donated."""
+    ``PSPState`` donated.  Each worker's forward and backward run under
+    the device scope ``train.grads``;
+    :func:`~repro.core.spmd_psp.psp_apply_tick` scopes the rest."""
     def grad_fn(p, tokens):
-        (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
-            p, {"tokens": tokens}, cfg)
-        return loss, clip_by_norm(g, 1.0)
+        with jax.named_scope("train.grads"):
+            (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                p, {"tokens": tokens}, cfg)
+            return loss, clip_by_norm(g, 1.0)
 
     return jax.jit(lambda s, b: psp_train_step(pcfg, grad_fn, opt.update,
                                                s, b), donate_argnums=(0,))
