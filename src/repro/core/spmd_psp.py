@@ -36,8 +36,7 @@ Mapping (DESIGN.md §3/§4):
 
 The per-tick protocol (one call of :func:`psp_train_step`):
 
-  1. every worker computes a gradient on **its own view** (SPMD always
-     computes; masks decide what lands),
+  1. every worker evaluates the loss on **its own view**,
   2. workers whose virtual clock completed *push*: the server applies the
      masked sum of their gradients through the optimizer,
   3. completed workers evaluate the barrier on a β-sample of the step
@@ -45,6 +44,17 @@ The per-tick protocol (one call of :func:`psp_train_step`):
      and draw the duration of their next local step; blocked workers hold
      (they re-sample next tick — the paper's "holds until condition is
      satisfied").
+
+The masks decide what runs.  Which workers push depends on the virtual
+clock alone, so it is known before any gradient exists: a tick on which
+nobody pushes (a blocked worker polling while a straggler computes) runs
+the forward pass only, for the reported loss, and skips the backward
+pass, the server update and its optimizer state.  That gate is a scalar
+``lax.cond`` outside the per-worker ``vmap`` (inside it a predicate would
+lower to a select, which runs both branches).  The pull writes the server
+model into the views of the allowed workers only, one in-place row write
+each, so a tick on which nobody is allowed writes no view.  A gated tick
+computes bit-for-bit the numbers of running every phase every tick.
 
 Elastic worker sets (churn)
 ---------------------------
@@ -473,6 +483,15 @@ def psp_apply_tick(
     push mask discards those columns identically either way, which is
     what makes the cluster bit-exact against the in-process trainer).
 
+    ``compute`` runs inside a ``lax.cond`` on "some worker pushes": the
+    update branch uses its gradients, the hold branch only its losses, so
+    there the gradients are dead code the compiler drops (the in-process
+    backward pass; the cluster's gradients arrive computed either way).
+    The pull loops over the allowed workers only and writes the server
+    model into each one's view in place.  ``metrics["updated"]`` and
+    ``metrics["pulled"]`` say whether the update ran and whether any view
+    was pulled.
+
     Each phase runs under a device scope (``jax.named_scope``) that a
     profiler trace reads back: ``train.barrier`` (churn, the barrier
     decision, the clock advance, the metrics), ``train.optimizer`` (the
@@ -492,39 +511,50 @@ def psp_apply_tick(
         else:
             key, k_bar, k_dur = jax.random.split(state.key, 3)
         alive = state.alive
-
-    # (1) every worker computes on its own (possibly stale) view
-    losses, grads = compute(state)
-
-    with jax.named_scope("train.optimizer"):
-        # (2) completions push to the server; departed workers are masked out
-        # of the psum — zero gradient, zero bytes
+        # completions push to the server; departed workers are masked out
+        # of the psum — zero gradient, zero bytes.  The mask reads the clock
+        # alone, so it decides before any gradient exists whether the
+        # server update runs at all.
         completed = state.busy_until <= state.now
         push_mask = completed & ~state.pushed & alive
-        denom = jnp.maximum(jnp.sum(push_mask), 1)
-        if cfg.contribution == "mean-alive":
-            # churn-aware scaling: divide by the carried alive-count EMA, not
-            # by this tick's pushing-set size — per-worker contribution stays
-            # stable as churn shrinks/grows the population.  Reads the OLD
-            # state (the EMA update lands below with the policy state).
-            scale = 1.0 / jnp.maximum(state.policy["denom"], 1.0)
-        else:
-            scale = jnp.where(cfg.contribution == "mean", 1.0 / denom, 1.0)
-
-        def _masked_sum(g):
-            m = push_mask.reshape((-1,) + (1,) * (g.ndim - 1))
-            return jnp.sum(jnp.where(m, g, 0), axis=0) * scale
-
-        server_grad = jax.tree.map(_masked_sum, grads)
         any_push = jnp.any(push_mask)
-        updates, new_opt = opt_update(server_grad, state.opt_state,
-                                      state.server_params)
-        new_params = jax.tree.map(
-            lambda p, u: jnp.where(any_push, p + u, p),
-            state.server_params, updates)
-        new_opt = jax.tree.map(
-            lambda new, old: jnp.where(any_push, new, old), new_opt,
-            state.opt_state)
+        denom = jnp.maximum(jnp.sum(push_mask), 1)
+
+    def _update(st):
+        # (1) every worker computes on its own (possibly stale) view,
+        # (2) the pushing workers' masked sum goes through the optimizer
+        losses, grads = compute(st)
+        with jax.named_scope("train.optimizer"):
+            if cfg.contribution == "mean-alive":
+                # churn-aware scaling: divide by the carried alive-count EMA,
+                # not by this tick's pushing-set size — per-worker
+                # contribution stays stable as churn shrinks/grows the
+                # population.  Reads the OLD state (the EMA update lands
+                # below with the policy state).
+                scale = 1.0 / jnp.maximum(st.policy["denom"], 1.0)
+            else:
+                scale = jnp.where(cfg.contribution == "mean", 1.0 / denom,
+                                  1.0)
+
+            def _masked_sum(g):
+                m = push_mask.reshape((-1,) + (1,) * (g.ndim - 1))
+                return jnp.sum(jnp.where(m, g, 0), axis=0) * scale
+
+            server_grad = jax.tree.map(_masked_sum, grads)
+            updates, new_opt = opt_update(server_grad, st.opt_state,
+                                          st.server_params)
+            new_params = jax.tree.map(jnp.add, st.server_params, updates)
+        return losses, new_params, new_opt
+
+    def _hold(st):
+        # no worker pushes: only the losses are read, so the gradients (and
+        # with them the backward pass) are dead code the compiler drops
+        losses, _ = compute(st)
+        return losses, st.server_params, st.opt_state
+
+    with jax.named_scope("train.optimizer"):
+        losses, new_params, new_opt = jax.lax.cond(any_push, _update, _hold,
+                                                   state)
         pushed = state.pushed | push_mask
 
     with jax.named_scope("train.barrier"):
@@ -545,11 +575,22 @@ def psp_apply_tick(
         new_pushed = jnp.where(allowed, False, pushed)
 
     with jax.named_scope("train.pull"):
-        def _pull(view, p):
-            m = allowed.reshape((-1,) + (1,) * p.ndim)
-            return jnp.where(m, p[None], view)
+        # allowed workers pull the fresh server model: one in-place write
+        # of it into each allowed worker's view, in index order (a select
+        # over all W views would rewrite every view on every tick)
+        n_pull = jnp.sum(allowed)
+        order = jnp.argsort(~allowed, stable=True)
 
-        new_views = jax.tree.map(_pull, state.views, new_params)
+        def _body(carry):
+            k, views = carry
+            i = order[k]
+            views = jax.tree.map(
+                lambda v, p: jax.lax.dynamic_update_index_in_dim(v, p, i, 0),
+                views, new_params)
+            return k + 1, views
+
+        _, new_views = jax.lax.while_loop(lambda c: c[0] < n_pull, _body,
+                                          (jnp.int32(0), state.views))
 
     with jax.named_scope("train.barrier"):
         if cfg.contribution == "mean-alive":
@@ -606,6 +647,10 @@ def psp_apply_tick(
                               jnp.sum(jnp.where(push_mask, losses, 0)) / denom,
                               jnp.mean(losses)),
             "pushes": jnp.sum(push_mask),
+            # which of the tick's gated phases ran: the server update (some
+            # worker pushed) and the views' pull (some worker was allowed)
+            "updated": any_push.astype(jnp.int32),
+            "pulled": (n_pull > 0).astype(jnp.int32),
             "allowed": jnp.sum(allowed),
             "blocked": jnp.sum(blocked),
             "alive": jnp.sum(alive),

@@ -9,6 +9,7 @@ churn equivalence lives in ``tests/test_elastic_equiv.py``.
 """
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,9 @@ import pytest
 
 from repro.core import env
 from repro.core.spmd_psp import (ChurnConfig, PSPConfig, elastic_drive,
-                                 linear_psp_task, psp_init, psp_train_step)
+                                 linear_psp_state, linear_psp_task,
+                                 make_psp_step_fn, psp_apply_tick, psp_init,
+                                 psp_train_step)
 
 D = 24
 
@@ -311,3 +314,199 @@ def test_jit_single_compilation(task):
     for _ in range(4):
         st, _ = step(st, (x, jnp.ones((4, 8))))
     assert calls == 1   # traced once — fully jittable barrier logic
+
+
+# --------------------------------------------------------------------------- #
+# the gated tick: the backward pass, the server update and the pull run only
+# on ticks where some worker pushes or pulls
+# --------------------------------------------------------------------------- #
+#: the pBSP benchmark cell's barrier and virtual clock
+#: (``bench/workloads/train.qwen2-0.5b.pbsp.json``, ``psp_seed`` 1)
+PBSP_CELL = dict(barrier="pbsp", n_workers=2, sample_size=1, staleness=3,
+                 straggler_frac=0.5, straggler_slowdown=4.0,
+                 base_compute=0.1, compute_jitter=0.5, poll_interval=0.02)
+
+
+def _select_cond(pred, true_fun, false_fun, *operands):
+    """``lax.cond`` as a select: both branches run and the predicate picks
+    one result."""
+    return jax.tree.map(lambda t, f: jnp.where(pred, t, f),
+                        true_fun(*operands), false_fun(*operands))
+
+
+def _hlo_computations(text: str) -> tuple[dict, str]:
+    """``{name: body lines}`` of an HLO module's text, and the entry's name."""
+    comps, cur, entry = {}, None, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            m = re.match(r"(ENTRY )?%?([\w.\-]+) ", line)
+            if m:
+                cur = m.group(2)
+                comps[cur] = []
+                entry = cur if m.group(1) else entry
+                continue
+        if cur is not None:
+            comps[cur].append(line)
+    return comps, entry
+
+
+def _reachable(comps: dict, root: str) -> set:
+    """``root`` and every computation it calls, fusions and loops included."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            for m in re.finditer(r"(?:calls|to_apply|body|condition|"
+                                 r"branch_computations)=\{?([^}\s]+(?:, "
+                                 r"%[\w.\-]+)*)", line):
+                todo += [n for n in re.findall(r"%?([\w.\-]+)", m.group(1))
+                         if n in comps]
+    return seen
+
+
+class TestGatedTick:
+    """``psp_apply_tick`` decides from the tick's push and pull masks which
+    phases run, with the same numbers as running every phase every tick."""
+
+    TICKS = 400
+
+    def test_gated_step_matches_ungated_constant_gradient_path(
+            self, task, monkeypatch):
+        w_true, grad_fn, opt_update = task
+        cfg = PSPConfig(**PBSP_CELL)
+        grads_of = jax.jit(jax.vmap(grad_fn))
+        batches, gated = [], []
+        step = jax.jit(make_psp_step_fn(cfg, grad_fn, opt_update))
+        st = linear_psp_state(cfg, D, init_seed=1)
+        kb = jax.random.PRNGKey(2)
+        for _ in range(self.TICKS):
+            kb, k1 = jax.random.split(kb)
+            x = jax.random.normal(k1, (cfg.n_workers, 16, D))
+            batches.append((x, x @ w_true))
+            forward = float(jnp.mean(grads_of(st.views, batches[-1])[0]))
+            st, m = step(st, batches[-1])
+            gated.append((jax.device_get(st), jax.device_get(m), forward))
+
+        # the update every tick: gradients computed outside the step for
+        # both workers, and the server update selected after it ran (the
+        # pull against the protocol: test_each_phase_follows_the_clock)
+        monkeypatch.setattr(jax.lax, "cond", _select_cond)
+        ungated = jax.jit(lambda s, losses, grads: psp_apply_tick(
+            cfg, opt_update, s, lambda _: (losses, grads)))
+        st = linear_psp_state(cfg, D, init_seed=1)
+        kinds = set()
+        for batch, (g_st, g_m, forward) in zip(batches, gated):
+            st, m = ungated(st, *grads_of(st.views, batch))
+            for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(g_st)):
+                np.testing.assert_array_equal(np.asarray(a), b)
+            assert int(m["pushes"]) == int(g_m["pushes"])
+            assert float(m["loss"]) == float(g_m["loss"])
+            if int(g_m["pushes"]) == 0:
+                assert abs(float(g_m["loss"]) - forward) <= 1e-6
+            kinds.add((int(g_m["pushes"]) > 0, int(g_m["allowed"]) > 0))
+        assert kinds == {(False, False), (False, True), (True, False),
+                         (True, True)}
+
+    @pytest.mark.parametrize("clock,at_once", [
+        (PBSP_CELL, 1),
+        # a 4x straggler holds three fast workers at the barrier, which
+        # then all pull at once
+        (dict(barrier="bsp", n_workers=4, compute_jitter=0.0,
+              straggler_frac=0.25), 3),
+    ], ids=["pbsp-cell", "bsp-straggler"])
+    def test_each_phase_follows_the_clock(self, task, clock, at_once):
+        """Against the protocol itself, tick by tick: the server moves by
+        the pushing workers' mean gradient exactly on ticks where the
+        clock says some worker pushes, and a worker's view becomes the new
+        server model exactly when its step advances."""
+        w_true, grad_fn, opt_update = task
+        cfg = PSPConfig(**clock)
+        grads_of = jax.jit(jax.vmap(grad_fn))
+        step = jax.jit(make_psp_step_fn(cfg, grad_fn, opt_update))
+        st = linear_psp_state(cfg, D, init_seed=1)
+        kb = jax.random.PRNGKey(2)
+        most = 0
+        for _ in range(self.TICKS):
+            kb, k1 = jax.random.split(kb)
+            x = jax.random.normal(k1, (cfg.n_workers, 16, D))
+            old = jax.device_get(st)
+            push = (old.busy_until <= old.now) & ~old.pushed & old.alive
+            g = np.asarray(grads_of(st.views, (x, x @ w_true))[1]["w"])
+            st, m = step(st, (x, x @ w_true))
+            new = jax.device_get(st)
+            assert int(m["pushes"]) == push.sum()
+            if push.any():
+                want = old.server_params["w"] - 0.1 * g[push].mean(axis=0)
+                np.testing.assert_allclose(new.server_params["w"], want,
+                                           rtol=0, atol=1e-6)
+                assert not np.array_equal(new.server_params["w"],
+                                          old.server_params["w"])
+            else:
+                np.testing.assert_array_equal(new.server_params["w"],
+                                              old.server_params["w"])
+            pulled = new.step > old.step
+            most = max(most, pulled.sum())
+            for w in range(cfg.n_workers):
+                np.testing.assert_array_equal(
+                    new.views["w"][w], new.server_params["w"] if pulled[w]
+                    else old.views["w"][w])
+        assert most == at_once
+
+    def test_counters_follow_the_masks(self):
+        cfg = PSPConfig(**PBSP_CELL)
+        _, it = elastic_drive(cfg, 8, 3000)
+        rows = np.array([[int(m[k]) for k in ("updated", "pushes", "pulled",
+                                              "allowed")] for _, m in it])
+        updated, pushes, pulled, allowed = rows.T
+        np.testing.assert_array_equal(updated, pushes > 0)
+        np.testing.assert_array_equal(pulled, allowed > 0)
+        # the cell's clock: ≈ 12% of ticks update the server, ≈ 12% pull,
+        # and a push lands on 5.9% of worker-ticks (`landed_fraction.train`)
+        assert round(updated.mean(), 4) == 0.1187
+        assert round(pulled.mean(), 4) == 0.1183
+        assert round(pushes.sum() / (2 * 3000), 4) == 0.0593
+
+    def test_poll_branch_compiles_without_the_backward_pass(self):
+        from repro.configs import get_config, reduced
+        from repro.launch.train import make_psp_step
+        from repro.models import init_model
+        from repro.optim import adamw, warmup_cosine
+        cfg = reduced(get_config("qwen2-0.5b"), n_layers=2, d_model=64)
+        opt = adamw(warmup_cosine(3e-3, 1, 3))
+        pcfg = PSPConfig(**PBSP_CELL)
+        state = jax.eval_shape(
+            lambda k: psp_init(pcfg, init_model(cfg, k), opt.init, k),
+            jax.random.PRNGKey(0))
+        toks = jax.ShapeDtypeStruct((2, 2, 16), jnp.int32)
+        comps, entry = _hlo_computations(
+            make_psp_step(cfg, pcfg, opt).lower(state, toks).compile()
+            .as_text())
+
+        def products(root):
+            return sum(bool(re.search(r" (dot|convolution)\(", line))
+                       for c in _reachable(comps, root) for line in comps[c])
+
+        conds = [re.findall(r"%([\w.\-]+)", m.group(1))
+                 for line in comps[entry]
+                 for m in [re.search(r"branch_computations=\{([^}]*)\}",
+                                     line)] if m]
+        # lax.cond lists the false branch first: hold, then update
+        (hold, update), = [b for b in conds if sum(map(products, b))]
+        assert 0 < products(hold) < products(update) / 2
+
+        # nothing the size of a parameter (or of a worker's views) is copied
+        # outside the branches, on every tick
+        sizes = {int(np.prod(p.shape)) * k
+                 for p in jax.tree.leaves(state.server_params)
+                 if p.size >= 1024 for k in (1, pcfg.n_workers)}
+        inside = set().union(*(_reachable(comps, b) for bs in conds
+                               for b in bs))
+        copied = [int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+                  for c in _reachable(comps, entry) - inside
+                  for line in comps[c]
+                  for m in [re.search(r"= f32\[([\d,]*)\]\S* copy\(", line)]
+                  if m]
+        assert not sizes.intersection(copied)
